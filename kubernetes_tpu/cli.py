@@ -926,6 +926,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(f"configuration valid: scheduler={cfg.scheduler_name} "
               f"solver={cfg.solver}")
         return 0
+    from kubernetes_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(cfg, args)
     return 0
 

@@ -989,7 +989,7 @@ def rule_r7_undeclared_readback(project: Project) -> List[Finding]:
         rel = fi.relpath.replace("\\", "/")
         if any(rel.endswith(m) for m in _R7_BOUNDARY_MODULES):
             continue
-        if rel.split("/", 1)[0] in ("tests", "tests_tpu", "scripts"):
+        if rel.split("/", 1)[0] in ("tests", "scripts"):
             # offline harnesses and parity oracles read device values by
             # design; the ratchet guards the serving/production modules
             continue
@@ -1075,7 +1075,7 @@ def rule_r8_mesh_gather(project: Project) -> List[Finding]:
         rel = fi.relpath.replace("\\", "/")
         if any(rel.endswith(m) for m in _R7_BOUNDARY_MODULES):
             continue
-        if rel.split("/", 1)[0] in ("tests", "tests_tpu", "scripts"):
+        if rel.split("/", 1)[0] in ("tests", "scripts"):
             # parity oracles and offline harnesses gather by design;
             # the gate guards the production cycle
             continue
@@ -1136,7 +1136,7 @@ _MUTATOR_METHODS = {
 
 #: files R9/R10 never look at: test fakes and offline harnesses are
 #: single-threaded by design, same scoping as R7/R8
-_LOCK_EXEMPT_TOPDIRS = ("tests", "tests_tpu", "scripts")
+_LOCK_EXEMPT_TOPDIRS = ("tests", "scripts")
 
 #: directly-blocking operations for R10 — exactly the shapes that have
 #: bitten this repo: hub RPC verbs, the declared d2h boundary, sleeps,

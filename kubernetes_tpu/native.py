@@ -1,13 +1,16 @@
 """ctypes bindings for the native host-runtime library (``native/ktpu.cc``).
 
-Auto-builds ``libktpu.so`` with the repo's Makefile on first use (cached);
-every entry point has a pure-numpy fallback so the package works without a
-toolchain — the native path is a performance tier, not a dependency.
+Auto-builds the library with the repo's Makefile on first use, under a
+name keyed by the hash of ``native/ktpu.cc`` and its Makefile: a ``.so``
+built from any other source is never loaded. Every entry point has a
+pure-numpy fallback so the package works without a toolchain — the
+native path is a performance tier, not a dependency.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -16,7 +19,6 @@ from typing import Optional, Tuple
 import numpy as np
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libktpu.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -24,6 +26,27 @@ _lib_tried = False
 
 #: feasibility sentinel shared with the device solvers (ops/assign.NEG)
 NEG = -1e30
+
+
+def _build() -> str:
+    """Path of the library built from the tree's ``ktpu.cc``: the name
+    carries the hash of the source and its Makefile, so only their build
+    can sit there. Built to a private name and renamed into place, so
+    concurrent builders (test workers) never load a half-written file."""
+    h = hashlib.sha256()
+    for name in ("ktpu.cc", "Makefile"):
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    digest = h.hexdigest()[:16]
+    path = os.path.join(_NATIVE_DIR, f"libktpu-{digest}.so")
+    if not os.path.exists(path):
+        tmp = f"{path}.{os.getpid()}.tmp"
+        subprocess.run(
+            ["make", "-s", f"TARGET={os.path.basename(tmp)}"],
+            cwd=_NATIVE_DIR, check=True, capture_output=True, timeout=120,
+        )
+        os.replace(tmp, path)
+    return path
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -34,12 +57,7 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         _lib_tried = True
         try:
-            if not os.path.exists(_LIB_PATH):
-                subprocess.run(
-                    ["make", "-s"], cwd=_NATIVE_DIR, check=True,
-                    capture_output=True, timeout=120,
-                )
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(_build())
             lib.hungarian_solve.argtypes = [
                 ctypes.c_int32, ctypes.c_int32,
                 np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS"),

@@ -22,6 +22,7 @@ declares, charging the bytes moved to a named site, and
 
 from __future__ import annotations
 
+import threading
 from collections import deque
 from typing import Dict, Optional, Tuple
 
@@ -78,6 +79,27 @@ def tree_nbytes(*trees) -> int:
                 n *= int(d)
             total += n * np.dtype(str(dtype)).itemsize
     return total
+
+
+#: Pallas kernel routes, "kernel:route" -> programs traced on that route
+#: (route: pallas | interpret | jnp | jnp:<why>). The kernels pick their
+#: route by a static shape rule while the caller is traced, so one entry
+#: per compiled program says which implementation it holds — the
+#: process-wide view of a choice no per-call digest can see.
+_KERNEL_ROUTES: Dict[str, int] = {}
+_KERNEL_ROUTES_LOCK = threading.Lock()
+
+
+def record_kernel_route(kernel: str, route: str) -> None:
+    """Count one trace of ``kernel`` on ``route`` (trace time only)."""
+    key = f"{kernel}:{route}"
+    with _KERNEL_ROUTES_LOCK:
+        _KERNEL_ROUTES[key] = _KERNEL_ROUTES.get(key, 0) + 1
+
+
+def kernel_routes() -> Dict[str, int]:
+    with _KERNEL_ROUTES_LOCK:
+        return dict(sorted(_KERNEL_ROUTES.items()))
 
 
 class JaxTelemetry:
@@ -271,4 +293,5 @@ class JaxTelemetry:
                     for (site, direction), row in sorted(
                         self.transfers.items())
                 },
+                "kernel_routes": kernel_routes(),
             }

@@ -58,11 +58,12 @@ import sys
 import threading
 import time
 
-# the --mesh arm family needs the virtual-device CPU mesh; defaults
-# only (a real TPU env var wins), set BEFORE jax initializes
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
+# the CPU only when the caller pins it (JAX_PLATFORMS=cpu): then the
+# mesh arms get 8 virtual devices, set BEFORE jax initializes. Unpinned,
+# JAX takes the machine's accelerator.
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", "")):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8").strip()
@@ -1551,6 +1552,7 @@ def run_incr_sweep(args, warm_buckets, serving_cfg: ServingConfig) -> int:
 
         record["platform"]["jax_backend"] = jax.default_backend()
         record["platform"]["devices"] = len(jax.devices())
+        record["platform"]["device_kind"] = jax.devices()[0].device_kind
     except Exception:
         pass
     for n in sizes:
@@ -1838,6 +1840,7 @@ def run_sparse_sweep(args, warm_buckets,
 
         record["platform"]["jax_backend"] = jax.default_backend()
         record["platform"]["devices"] = len(jax.devices())
+        record["platform"]["device_kind"] = jax.devices()[0].device_kind
     except Exception:
         pass
     for n in sizes:
@@ -2234,6 +2237,7 @@ def main(argv=None) -> int:
 
         record["platform"]["jax_backend"] = jax.default_backend()
         record["platform"]["devices"] = len(jax.devices())
+        record["platform"]["device_kind"] = jax.devices()[0].device_kind
     except Exception:
         pass
 

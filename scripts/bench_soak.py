@@ -59,11 +59,12 @@ import sys
 import threading
 import time
 
-# virtual-device CPU mesh defaults; a real TPU env wins. Must be set
-# BEFORE jax initializes (bench_churn does the same).
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
+# the CPU only when the caller pins it (JAX_PLATFORMS=cpu): then the
+# mesh arms get 8 virtual devices, set BEFORE jax initializes. Unpinned,
+# JAX takes the machine's accelerator.
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", "")):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8").strip()
@@ -749,6 +750,7 @@ def main(argv=None) -> int:
 
         record["platform"]["jax_backend"] = jax.default_backend()
         record["platform"]["devices"] = len(jax.devices())
+        record["platform"]["device_kind"] = jax.devices()[0].device_kind
     except Exception:
         pass
 

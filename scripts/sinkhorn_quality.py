@@ -16,7 +16,7 @@ near-equal cold columns.
 The construction and the comparison are IMPORTED from
 tests/test_sinkhorn.py (the pinned single source — this script only
 scales it up), so the published evidence can never drift from the
-regression test. Run with JAX_PLATFORMS=cpu for the wedge-safe path.
+regression test. Run with JAX_PLATFORMS=cpu for the CPU path.
 """
 from __future__ import annotations
 

@@ -2,6 +2,7 @@
 gang/coscheduling via batched Sinkhorn assignment)."""
 
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops.sinkhorn import sinkhorn_plan
@@ -157,10 +158,11 @@ def test_pallas_handles_unpadded_shapes():
 
 
 def test_block_shapes_fixed_point():
-    """The compile probe re-derives the tiling from the padded shape via
-    `_scale_pallas`; `_block_shapes` must therefore be a fixed point on
-    its own output or the probe validates a different kernel config than
-    the real call runs (round-3 review finding)."""
+    """`_block_shapes` is the one tiling both the route rule
+    (`pallas_fits`) and `_scale_pallas` use: 128-multiple blocks, slabs
+    inside the budget wherever shrinking can still act, and a fixed
+    point on its own output (a padded shape re-derives the same
+    tiling)."""
     from kubernetes_tpu.ops.sinkhorn import VMEM_SLAB_BUDGET, _block_shapes
 
     shapes = [(8192, 5120), (64, 16), (303, 41), (2048, 1024), (2300, 4000),
@@ -177,6 +179,22 @@ def test_block_shapes_fixed_point():
         # fixed point: re-deriving from the padded shape with the chosen
         # blocks as caps reproduces the identical config
         assert _block_shapes(P, N, bp, bn) == (bp, bn, P, N)
+
+
+@pytest.mark.parametrize("shape,fits", [
+    ((8192, 5120), True),    # the headline bucket: 128x128 blocks
+    ((4096, 8192), True),    # a pipelined chunk over 5k nodes' bucket
+    ((64, 16), True),
+    ((8192, 51200), False),  # config-5 width: 26 MB u slab
+    ((16384, 5120), False),  # 8 MB v slab at the 128 floor
+])
+def test_pallas_fits_static_rule(shape, fits):
+    """The route rule: Pallas exactly where both 128-floor slabs fit the
+    budget; the jnp route otherwise (tests/test_chip_compile.py pins
+    that the v5e compiler really refuses the 51200-wide kernel)."""
+    from kubernetes_tpu.ops.sinkhorn import pallas_fits
+
+    assert pallas_fits(*shape) is fits
 
 
 def tied_preferences_workload(n_hot=4, n_cold=20, n_steep=16,
@@ -239,8 +257,7 @@ def tied_preferences_workload(n_hot=4, n_cold=20, n_steep=16,
 def run_tied_preferences_comparison(**sizes):
     """Solve the tied-preferences workload with argmax and with the OT
     plan; returns {False: points, True: points} after asserting both
-    placements are full. Shared by the CPU test here and the compiled
-    TPU test (tests_tpu/test_solver_compiled.py)."""
+    placements are full."""
     from kubernetes_tpu.ops.arrays import (
         nodes_to_device,
         pods_to_device,

@@ -21,11 +21,11 @@ import os
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-#: every first-party python root (tests_tpu is TPU-only and excluded from
-#: tier-1 *execution*, but it must still parse — a SyntaxError there
-#: would kill a hardware run at collection time the same way)
-PARSE_ROOTS = ("kubernetes_tpu", "scripts", "tests", "tests_tpu")
-PARSE_FILES = ("bench.py", "__graft_entry__.py")
+#: every first-party python root, plus the top-level entry scripts
+#: (chip_smoke.py runs only on the chip — a SyntaxError there would
+#: surface nowhere else)
+PARSE_ROOTS = ("kubernetes_tpu", "scripts", "tests")
+PARSE_FILES = ("bench.py", "__graft_entry__.py", "chip_smoke.py")
 
 #: what the lint gate enforces (the acceptance surface of the linter CLI:
 #: ``python -m kubernetes_tpu.lint kubernetes_tpu/ scripts/ tests/``)
