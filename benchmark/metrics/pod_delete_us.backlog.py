@@ -1,0 +1,8 @@
+"""Mean host microseconds per ``on_pod_delete`` call (the deletion of
+a bound pod), from the program's own annotation in the traced sub-window."""
+from host_spans import mean_s
+
+
+def read(view):
+    s = mean_s(view, "on_pod_delete")
+    return None if s is None else s * 1e6

@@ -109,6 +109,7 @@ class Observability:
         self._scratch: dict = {}
         self._sinkhorn_stats = None  # device (2,) [iters, residual]
         self._retraces_at_begin = 0
+        self._compiles_at_begin = (0, 0.0)
         #: takeover reconciliation happens BETWEEN cycles — the flag
         #: parks here until the next begin_cycle stamps it onto that
         #: cycle's record (value = elector epoch, or 1 when unknown)
@@ -151,6 +152,7 @@ class Observability:
         self._sinkhorn_stats = None
         self._retraces_at_begin = self.jax.retrace_total()
         self._d2h_at_begin = self.jax.d2h_bytes_total()
+        self._compiles_at_begin = self.jax.compiles_total()
         self._lockfind_at_begin = (
             self.lock_sanitizer.total_findings()
             if self.lock_sanitizer is not None else 0)
@@ -328,6 +330,8 @@ class Observability:
         if not self.config.enabled:
             return None
         s = self._scratch
+        n0, s0 = self._compiles_at_begin
+        n1, s1 = self.jax.compiles_total()
         # idle poll cycles (empty batch, nothing attempted, no incident
         # activity) are not black-box material: recording them would let
         # ~a minute of idle 0.25s serve-loop polls evict every record of
@@ -396,6 +400,8 @@ class Observability:
             scenario=s.get("scenario", {}),
             preflight=s.get("preflight", ""),
             oom_forensic=s.get("oom_forensic", ""),
+            compiles=n1 - n0,
+            compile_s=s1 - s0,
         )
         # perf ledger (obs/ledger.py): fold the cycle's measured phase
         # costs in, confront them with the cost model, run the SLO
@@ -417,6 +423,7 @@ class Observability:
                     res.modeled_s = entry.modeled_s
                     res.model_efficiency = entry.efficiency
                 trace.counter("model_efficiency", eff=entry.efficiency)
+        trace.counter("compiles", n=rec.compiles, s=rec.compile_s)
         # device-memory ledger (obs/memledger.py): the cycle-boundary
         # measured sample + the modeled-vs-measured confrontation —
         # host metadata reads only, zero new syncs (the freshness/-1

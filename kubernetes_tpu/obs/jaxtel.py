@@ -102,6 +102,36 @@ def kernel_routes() -> Dict[str, int]:
         return dict(sorted(_KERNEL_ROUTES.items()))
 
 
+#: XLA backend compiles in this process, [count, seconds], from JAX's own
+#: monitoring event — what ``record_call``'s digests can only guess at
+#: (a persistent-cache hit traces without compiling). One listener for
+#: the process: every scheduler a process builds reads the same counter
+#: and diffs it per cycle.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILES = [0, 0.0]
+_COMPILES_LOCK = threading.Lock()
+_compile_listener_registered = False
+
+
+def _on_jax_event_duration(event: str, duration: float, **kw) -> None:
+    if event == _COMPILE_EVENT:
+        with _COMPILES_LOCK:
+            _COMPILES[0] += 1
+            _COMPILES[1] += float(duration)
+
+
+def _register_compile_listener() -> None:
+    global _compile_listener_registered
+    with _COMPILES_LOCK:
+        if _compile_listener_registered:
+            return
+        _compile_listener_registered = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_jax_event_duration)
+
+
 class JaxTelemetry:
     """Per-site compile-cache observation + transfer accounting.
 
@@ -149,6 +179,7 @@ class JaxTelemetry:
         self._retrace_at: Dict[str, deque] = {}
         #: (site, direction) -> [count, bytes]
         self.transfers: Dict[Tuple[str, str], list] = {}
+        _register_compile_listener()
 
     # -- compile cache ------------------------------------------------------
 
@@ -209,6 +240,13 @@ class JaxTelemetry:
             if site is not None:
                 return self.storms.get(site, 0)
             return sum(self.storms.values())
+
+    def compiles_total(self) -> Tuple[int, float]:
+        """``(compiles, seconds)`` of real XLA backend compiles in this
+        process since the first telemetry object was built — the flight
+        recorder diffs it per cycle (``compiles=``)."""
+        with _COMPILES_LOCK:
+            return _COMPILES[0], _COMPILES[1]
 
     # -- transfers ----------------------------------------------------------
 

@@ -125,6 +125,11 @@ class CycleRecord:
     #: OOM forensic flag (memledger.record_oom ran this cycle — the
     #: ``mem=`` dump flag routes the postmortem to /debug/memory)
     oom_forensic: str = ""
+    #: XLA backend compiles that ran during this cycle and their seconds
+    #: (JaxTelemetry.compiles_total deltas: real compiles, not digests —
+    #: ``compiles=`` dump flag)
+    compiles: int = 0
+    compile_s: float = 0.0
 
     def to_json(self) -> dict:
         return {
@@ -184,6 +189,9 @@ class CycleRecord:
             **({"preflight": self.preflight} if self.preflight else {}),
             **({"oom_forensic": self.oom_forensic}
                if self.oom_forensic else {}),
+            **({"compiles": self.compiles,
+                "compile_s": round(self.compile_s, 6)}
+               if self.compiles else {}),
         }
 
 
@@ -287,6 +295,8 @@ class FlightRecorder:
                        if r.mem_measured_bytes >= 0 else ""))
             if r.preflight and r.preflight != "ok":
                 flags.append(f"preflight={r.preflight}")
+            if r.compiles:
+                flags.append(f"compiles={r.compiles}:{r.compile_s:.2f}s")
             spans = " ".join(
                 f"{k}={v*1000:.1f}ms" for k, v in sorted(r.spans.items()))
             lines.append(

@@ -14,20 +14,46 @@ containment on one pid/tid).
 
 Everything here is host code on an injectable clock: deterministic under
 fake clocks, no wall-clock reads beyond ``time.monotonic`` (graftlint R4
-stays green)."""
+stays green).
+
+Every span (and the root) is mirrored into ``jax.profiler`` through
+:func:`annotate` while a profiler session is active, so a device trace
+carries the program's phases under the same names, on its own clock and
+on the thread that ran them. With no profiler active the mirror costs
+one ``TraceAnnotation.is_enabled()`` check per span."""
 
 from __future__ import annotations
 
 import logging
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 logger = logging.getLogger("kubernetes_tpu.trace")
 
 #: the reference logs steps that took >= 50% of a (threshold/len) share;
 #: we keep it simple: log everything when over threshold.
 DEFAULT_THRESHOLD_S = 0.1  # LogIfLong(100*time.Millisecond)
+
+_NO_ANNOTATION = nullcontext()
+
+
+def annotate(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` while a profiler
+    session is active, else a shared no-op context: host phases between
+    cycles (the event handlers, the serving loop's waits) use it
+    directly, cycle spans through :class:`Trace`."""
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name)
+    return _NO_ANNOTATION
+
+
+def _enter(name: str):
+    a = annotate(name)
+    a.__enter__()
+    return a
 
 
 class Span:
@@ -66,6 +92,10 @@ class Trace:
         self.start = clock()
         self.root = Span(name, self.start, **fields)
         self._stack: List[Span] = [self.root]
+        #: (span, profiler annotation) of the frames still open, root
+        #: first — unwound LIFO with ``_stack``
+        self._annotations: List[Tuple[Span, object]] = [
+            (self.root, _enter(name))]
         #: flat (timestamp, msg) list — the seed-compat view of steps
         self.steps: List[Tuple[float, str]] = []
         #: (timestamp, track name, {series: value}) counter samples —
@@ -123,6 +153,7 @@ class Trace:
         sp = Span(name, self.clock(), **fields)
         self._stack[-1].children.append(sp)
         self._stack.append(sp)
+        self._annotations.append((sp, _enter(name)))
         return sp
 
     def end_span(self, sp: Span) -> None:
@@ -134,6 +165,18 @@ class Trace:
             self._stack.pop()
         if self._stack:
             self._stack.pop()
+        self._unwind(sp)
+
+    def _unwind(self, sp: Optional[Span] = None) -> None:
+        """Close profiler annotations LIFO down to and including ``sp``'s
+        (every one when ``sp`` is None or not open), as ``end_span``
+        pops the stack."""
+        ann = self._annotations
+        while ann:
+            s, a = ann.pop()
+            a.__exit__(None, None, None)
+            if s is sp:
+                break
 
     @contextmanager
     def span(self, name: str, **fields):
@@ -157,6 +200,7 @@ class Trace:
         if self.root.end is None:
             self.root.end = self.clock()
         self._stack = [self.root]
+        self._unwind()
 
     def span_durations(self) -> Dict[str, float]:
         """Flat {span name: seconds} over the whole tree (later duplicate

@@ -319,12 +319,13 @@ def _scatter_node_rows_donated(resident: DeviceNodes, sub: DeviceNodes,
     or re-uploads the full table. The caller (SchedulerCache) is the sole
     owner of the resident arrays, which is what makes donation safe."""
     out = {}
-    for name in DeviceNodes._fields:
-        if name in _NODE_NON_ROW_FIELDS:
-            continue
-        r = getattr(resident, name)
-        s = getattr(sub, name)
-        out[name] = r.at[idx].set(s, mode="drop")
+    with jax.named_scope("delta_scatter"):
+        for name in DeviceNodes._fields:
+            if name in _NODE_NON_ROW_FIELDS:
+                continue
+            r = getattr(resident, name)
+            s = getattr(sub, name)
+            out[name] = r.at[idx].set(s, mode="drop")
     return DeviceNodes(valid=resident.valid, zone_valid=sub.zone_valid,
                        **out)
 

@@ -547,8 +547,9 @@ def _lean_rounds(pods, nodes, sel, rank, lean_plan, max_rounds,
     init = (jnp.full((P,), -1, jnp.int32), usage_from_nodes(nodes),
             jnp.asarray(True), jnp.asarray(0, jnp.int32),
             jnp.asarray(False), jnp.full((2,), -1.0, jnp.float32))
-    assigned, u, _, rounds, _, sk_stats = jax.lax.while_loop(
-        cond, round_body, init)
+    with jax.named_scope("lean_rounds"):
+        assigned, u, _, rounds, _, sk_stats = jax.lax.while_loop(
+            cond, round_body, init)
     return assigned, u, rounds, sk_stats
 
 
@@ -906,8 +907,9 @@ def _batch_impl(pods, nodes, sel, topo, weights_key, max_rounds, per_node_cap,
             jnp.asarray(True), jnp.asarray(0, jnp.int32),
             jnp.asarray(False), jnp.full((2,), -1.0, jnp.float32),
             u0_init.astype(jnp.float32), v0_init.astype(jnp.float32))
-    assigned, u, _, rounds, _, sk_stats, sk_u, sk_v = jax.lax.while_loop(
-        cond, round_body, init)
+    with jax.named_scope("general_rounds"):
+        assigned, u, _, rounds, _, sk_stats, sk_u, sk_v = jax.lax.while_loop(
+            cond, round_body, init)
     if potentials_out:
         return assigned, u, rounds, sk_stats, (sk_u, sk_v)
     return assigned, u, rounds, sk_stats
@@ -1194,49 +1196,50 @@ def _device_validate_impl(assigned, usage_requested, pods, nodes,
     driver's single end-of-solve readback."""
     from kubernetes_tpu.ops.predicates import BIT
 
-    P = pods.req.shape[0]
-    valid = pods.valid
-    nvalid = nodes.valid
-    N = nvalid.shape[0]
-    a = assigned[:P]
-    if not jnp.issubdtype(a.dtype, jnp.integer):
-        # a lying solver returning floats: finiteness first, then the
-        # integer-valuedness check, then proceed on the floored values —
-        # the same precedence the host checker applies
-        fin_a_bad = ~jnp.all(jnp.isfinite(a))
-        dtype_bad = jnp.any(jnp.where(jnp.isfinite(a), a != jnp.floor(a),
-                                      False))
-        a = jnp.where(jnp.isfinite(a), a, -2.0).astype(jnp.int32)
-    else:
-        fin_a_bad = jnp.asarray(False)
-        dtype_bad = jnp.asarray(False)
-        a = a.astype(jnp.int32)
-    range_bad = jnp.any(valid & ((a < -1) | (a >= N)))
-    sel = valid & (a >= 0)
-    ac = jnp.clip(a, 0, N - 1)
-    invalid_node = jnp.any(sel & ~nvalid[ac])
-    fin_bad = ~jnp.all(jnp.isfinite(usage_requested))
-    res_on = enabled_mask is None or bool(
-        enabled_mask & (1 << BIT["PodFitsResources"]))
-    if res_on:
-        req = pods.req
-        base = nodes.requested
-        alloc = nodes.allocatable
-        w = sel.astype(req.dtype)[:, None]
-        add = jnp.zeros_like(base).at[jnp.where(sel, ac, 0)].add(req * w)
-        tol = 1e-5 * jnp.maximum(alloc, 1.0) + 1e-6
-        pre_ok = base <= alloc + tol
-        over = (base + add > alloc + tol) & nvalid[:, None] & (add > 0)
-        cap_bad = jnp.any(over & pre_ok)
-    else:
-        cap_bad = jnp.asarray(False)
-    code = jnp.where(
-        fin_a_bad, 5, jnp.where(
-            dtype_bad, 2, jnp.where(
-                range_bad, 3, jnp.where(
-                    invalid_node, 4, jnp.where(
-                        fin_bad, 5, jnp.where(cap_bad, 6, 0))))))
-    return code.astype(jnp.int32), jnp.sum(sel, dtype=jnp.int32)
+    with jax.named_scope("device_validate"):
+        P = pods.req.shape[0]
+        valid = pods.valid
+        nvalid = nodes.valid
+        N = nvalid.shape[0]
+        a = assigned[:P]
+        if not jnp.issubdtype(a.dtype, jnp.integer):
+            # a lying solver returning floats: finiteness first, then the
+            # integer-valuedness check, then proceed on the floored values —
+            # the same precedence the host checker applies
+            fin_a_bad = ~jnp.all(jnp.isfinite(a))
+            dtype_bad = jnp.any(jnp.where(jnp.isfinite(a), a != jnp.floor(a),
+                                          False))
+            a = jnp.where(jnp.isfinite(a), a, -2.0).astype(jnp.int32)
+        else:
+            fin_a_bad = jnp.asarray(False)
+            dtype_bad = jnp.asarray(False)
+            a = a.astype(jnp.int32)
+        range_bad = jnp.any(valid & ((a < -1) | (a >= N)))
+        sel = valid & (a >= 0)
+        ac = jnp.clip(a, 0, N - 1)
+        invalid_node = jnp.any(sel & ~nvalid[ac])
+        fin_bad = ~jnp.all(jnp.isfinite(usage_requested))
+        res_on = enabled_mask is None or bool(
+            enabled_mask & (1 << BIT["PodFitsResources"]))
+        if res_on:
+            req = pods.req
+            base = nodes.requested
+            alloc = nodes.allocatable
+            w = sel.astype(req.dtype)[:, None]
+            add = jnp.zeros_like(base).at[jnp.where(sel, ac, 0)].add(req * w)
+            tol = 1e-5 * jnp.maximum(alloc, 1.0) + 1e-6
+            pre_ok = base <= alloc + tol
+            over = (base + add > alloc + tol) & nvalid[:, None] & (add > 0)
+            cap_bad = jnp.any(over & pre_ok)
+        else:
+            cap_bad = jnp.asarray(False)
+        code = jnp.where(
+            fin_a_bad, 5, jnp.where(
+                dtype_bad, 2, jnp.where(
+                    range_bad, 3, jnp.where(
+                        invalid_node, 4, jnp.where(
+                            fin_bad, 5, jnp.where(cap_bad, 6, 0))))))
+        return code.astype(jnp.int32), jnp.sum(sel, dtype=jnp.int32)
 
 
 def device_validate(assigned, usage: UsageState, pods: DevicePods,

@@ -41,6 +41,7 @@ import numpy as np
 
 from kubernetes_tpu.api.types import Pod
 from kubernetes_tpu.cache import SchedulerCache
+from kubernetes_tpu.obs.trace import annotate
 from kubernetes_tpu.ops.arrays import (
     nodes_to_device,
     pods_to_device,
@@ -690,133 +691,144 @@ class Scheduler:
         gets the same view without each needing the selector."""
         from kubernetes_tpu.api.types import is_pod_terminated
 
-        if is_pod_terminated(pod):
-            return
-        if pod.node_name:
-            self.cache.add_pod(pod)
-            self.queue.assigned_pod_added(pod)
-        elif self.responsible_for(pod):
-            self.queue.add(pod)
+        with annotate("on_pod_add"):
+            if is_pod_terminated(pod):
+                return
+            if pod.node_name:
+                self.cache.add_pod(pod)
+                self.queue.assigned_pod_added(pod)
+            elif self.responsible_for(pod):
+                self.queue.add(pod)
 
     def on_pod_update(self, old: Pod, new: Pod) -> None:
         from kubernetes_tpu.api.types import is_pod_terminated
 
-        if is_pod_terminated(new):
-            # terminal phase hop: the field-selected informer delivers
-            # this as a DELETE (the pod left the selector) — its node
-            # capacity is released even on feeds without the selector
-            # (the gRPC snapshot bridge, a selector-less Reflector)
-            self.on_pod_delete(new)
-            return
-        if new.node_name:
-            # a Permit-parked pod bound by another writer must leave the
-            # waiting map BEFORE cache.add_pod flips its state to ADDED —
-            # otherwise _process_waiting later calls forget_pod on a
-            # non-assumed pod and aborts the whole cycle (same cleanup
-            # on_pod_delete does for parked pods)
-            wp = self.framework.waiting.get(new.key())
-            if wp is not None:
-                self.framework.waiting.remove(new.key())
-                self.volume_binder.forget_pod_volumes(new.key())
-                self.framework.run_unreserve(
-                    self._cycle_states.get(new.key()) or _new_cycle_state(),
-                    wp.pod, wp.node_name,
-                )
-            self._cycle_states.pop(new.key(), None)
-            # add_pod (not update_pod): an unassigned->assigned transition
-            # must CONFIRM a pending assumption, or the TTL would expire a
-            # successfully bound pod and double-book its capacity
-            self.cache.add_pod(new)
-            # ... and must LEAVE the scheduling queue: the reference's
-            # unassigned-pod informer filter turns this transition into a
-            # queue delete (eventhandlers.go addAllEventHandlers pod
-            # FilterFunc). Without it, a pod bound by another writer (HA
-            # peer, competing scheduler) would be scheduled again here and
-            # double-booked.
-            self.queue.delete(new.key())
-            # journey: our own bind already completed it at the success
-            # tail (this no-ops); a COMPETING writer's bind closes it
-            # here as gone — it never bound through this scheduler
-            self.obs.journeys.note_gone(new.key())
-            # AssignedPodUpdated: wake only affinity-matching waiters, not
-            # the whole unschedulableQ (eventhandlers.go)
-            self.queue.assigned_pod_added(new)
-        elif self.responsible_for(new):
-            if new != old:
-                # a pending pod updated IN PLACE (same uid — labels or
-                # selector edited through PATCH): the packer's per-pod
-                # ref cache and the pack-table memo are keyed by
-                # (key, uid) + universe signature, and a changed spec
-                # whose values are all already interned moves neither —
-                # forget the pod so the next pack re-interns and the
-                # memoized tables (pack epoch) invalidate
-                self.cache.packer.forget_pod(new.key())
-            self.queue.update(old.key(), new)
-        elif self.responsible_for(old):
-            # responsible -> not-responsible transition: the reference's
-            # FilteringResourceEventHandler turns this into a Delete, so
-            # the stale spec must leave our queues (schedulerName is
-            # immutable in the real API, but this ingestion surface takes
-            # arbitrary updates). Pod-keyed side state must leave with it
-            # or it outlives the pod (the soak sentinels watch exactly
-            # these dicts for monotonic growth)
-            self.queue.delete(old.key())
-            self._cycle_states.pop(old.key(), None)
-            self.why_pending.pop(old.key(), None)
-            self._note_gone(old.key())
+        with annotate("on_pod_update"):
+            if is_pod_terminated(new):
+                # terminal phase hop: the field-selected informer delivers
+                # this as a DELETE (the pod left the selector) — its node
+                # capacity is released even on feeds without the selector
+                # (the gRPC snapshot bridge, a selector-less Reflector)
+                self.on_pod_delete(new)
+                return
+            if new.node_name:
+                # a Permit-parked pod bound by another writer must leave the
+                # waiting map BEFORE cache.add_pod flips its state to ADDED —
+                # otherwise _process_waiting later calls forget_pod on a
+                # non-assumed pod and aborts the whole cycle (same cleanup
+                # on_pod_delete does for parked pods)
+                wp = self.framework.waiting.get(new.key())
+                if wp is not None:
+                    self.framework.waiting.remove(new.key())
+                    self.volume_binder.forget_pod_volumes(new.key())
+                    self.framework.run_unreserve(
+                        self._cycle_states.get(new.key())
+                        or _new_cycle_state(),
+                        wp.pod, wp.node_name,
+                    )
+                self._cycle_states.pop(new.key(), None)
+                # add_pod (not update_pod): an unassigned->assigned transition
+                # must CONFIRM a pending assumption, or the TTL would expire a
+                # successfully bound pod and double-book its capacity
+                self.cache.add_pod(new)
+                # ... and must LEAVE the scheduling queue: the reference's
+                # unassigned-pod informer filter turns this transition into a
+                # queue delete (eventhandlers.go addAllEventHandlers pod
+                # FilterFunc). Without it, a pod bound by another writer (HA
+                # peer, competing scheduler) would be scheduled again here and
+                # double-booked.
+                self.queue.delete(new.key())
+                # journey: our own bind already completed it at the success
+                # tail (this no-ops); a COMPETING writer's bind closes it
+                # here as gone — it never bound through this scheduler
+                self.obs.journeys.note_gone(new.key())
+                # AssignedPodUpdated: wake only affinity-matching waiters, not
+                # the whole unschedulableQ (eventhandlers.go)
+                self.queue.assigned_pod_added(new)
+            elif self.responsible_for(new):
+                if new != old:
+                    # a pending pod updated IN PLACE (same uid — labels or
+                    # selector edited through PATCH): the packer's per-pod
+                    # ref cache and the pack-table memo are keyed by
+                    # (key, uid) + universe signature, and a changed spec
+                    # whose values are all already interned moves neither —
+                    # forget the pod so the next pack re-interns and the
+                    # memoized tables (pack epoch) invalidate
+                    self.cache.packer.forget_pod(new.key())
+                self.queue.update(old.key(), new)
+            elif self.responsible_for(old):
+                # responsible -> not-responsible transition: the reference's
+                # FilteringResourceEventHandler turns this into a Delete, so
+                # the stale spec must leave our queues (schedulerName is
+                # immutable in the real API, but this ingestion surface takes
+                # arbitrary updates). Pod-keyed side state must leave with it
+                # or it outlives the pod (the soak sentinels watch exactly
+                # these dicts for monotonic growth)
+                self.queue.delete(old.key())
+                self._cycle_states.pop(old.key(), None)
+                self.why_pending.pop(old.key(), None)
+                self._note_gone(old.key())
 
     def on_pod_delete(self, pod: Pod) -> None:
-        key = pod.key()
-        self._note_gone(key)
-        # a bind whose ambiguous verification was parked resolves by
-        # deletion: the pod is gone whatever the RPC did — release the
-        # held assumption (parked pods carry no TTL, so nothing else
-        # would free this capacity)
-        parked = self._ambiguous_binds.pop(key, None)
-        if parked is not None and self.cache.is_assumed(key):
-            apod, anode, ast = parked
-            self.cache.forget_pod(key)
-            self.volume_binder.forget_pod_volumes(key)
-            self.framework.run_unreserve(
-                ast or _new_cycle_state(), apod, anode)
-        # a Permit-parked pod is assumed in the cache and holds capacity —
-        # deletion must release both the wait entry and the assumption
-        wp = self.framework.waiting.get(key)
-        if wp is not None:
-            self.framework.waiting.remove(key)
-            self.cache.forget_pod(key)
-            self.volume_binder.forget_pod_volumes(key)
-            self.framework.run_unreserve(
-                self._cycle_states.get(key) or _new_cycle_state(), wp.pod,
-                wp.node_name,
-            )
-        if pod.node_name:
-            self.cache.remove_pod(key)
-            self.queue.move_all_to_active()
-        else:
-            self.queue.delete(key)
-        self.cache.packer.forget_pod(key)
-        self._cycle_states.pop(key, None)
-        self.why_pending.pop(key, None)
+        with annotate("on_pod_delete"):
+            key = pod.key()
+            self._note_gone(key)
+            # a bind whose ambiguous verification was parked resolves by
+            # deletion: the pod is gone whatever the RPC did — release the
+            # held assumption (parked pods carry no TTL, so nothing else
+            # would free this capacity)
+            parked = self._ambiguous_binds.pop(key, None)
+            if parked is not None and self.cache.is_assumed(key):
+                apod, anode, ast = parked
+                self.cache.forget_pod(key)
+                self.volume_binder.forget_pod_volumes(key)
+                self.framework.run_unreserve(
+                    ast or _new_cycle_state(), apod, anode)
+            # a Permit-parked pod is assumed in the cache and holds
+            # capacity — deletion must release both the wait entry and the
+            # assumption
+            wp = self.framework.waiting.get(key)
+            if wp is not None:
+                self.framework.waiting.remove(key)
+                self.cache.forget_pod(key)
+                self.volume_binder.forget_pod_volumes(key)
+                self.framework.run_unreserve(
+                    self._cycle_states.get(key) or _new_cycle_state(), wp.pod,
+                    wp.node_name,
+                )
+            if pod.node_name:
+                with annotate("cache.remove_pod"):
+                    self.cache.remove_pod(key)
+                with annotate("queue.move_all_to_active"):
+                    self.queue.move_all_to_active()
+            else:
+                self.queue.delete(key)
+            with annotate("packer.forget_pod"):
+                self.cache.packer.forget_pod(key)
+            self._cycle_states.pop(key, None)
+            self.why_pending.pop(key, None)
 
     def on_node_add(self, node) -> None:
-        self.cache.add_node(node)
-        self.node_tree.add_node(node)
-        self.queue.move_all_to_active()
+        with annotate("on_node_add"):
+            self.cache.add_node(node)
+            self.node_tree.add_node(node)
+            self.queue.move_all_to_active()
 
     def on_node_update(self, node) -> None:
-        old = self.cache.node(node.name)
-        if old is not None:
-            self.node_tree.remove_node(old)
-        self.cache.update_node(node)
-        self.node_tree.add_node(node)
-        self.queue.move_all_to_active()
+        with annotate("on_node_update"):
+            old = self.cache.node(node.name)
+            if old is not None:
+                self.node_tree.remove_node(old)
+            self.cache.update_node(node)
+            self.node_tree.add_node(node)
+            self.queue.move_all_to_active()
 
     def on_node_delete(self, name: str) -> None:
-        old = self.cache.node(name)
-        if old is not None:
-            self.node_tree.remove_node(old)
-        self.cache.remove_node(name)
+        with annotate("on_node_delete"):
+            old = self.cache.node(name)
+            if old is not None:
+                self.node_tree.remove_node(old)
+            self.cache.remove_node(name)
 
     def set_volume_state(self, pvcs=(), pvs=(), classes=()) -> None:
         """PV/PVC/StorageClass informer feed. Any volume-state change can
@@ -1274,6 +1286,8 @@ class Scheduler:
         trace = self.obs.begin_cycle(self.queue.scheduling_cycle)
         if flush_trigger:
             self.obs.note_microbatch(flush_trigger, window_s)
+        # everything before the snapshot: queue upkeep, the pop, PreFilter
+        pop_span = trace.begin_span("pop")
         self.queue.tick()
         self._reap_expired_assumptions()
         self._verify_ambiguous_binds()
@@ -1284,6 +1298,7 @@ class Scheduler:
         self._process_waiting(res)
         batch = self.queue.pop_batch(self.max_batch)
         if not batch:
+            trace.end_span(pop_span)
             res.elapsed_s = self.clock() - t0
             self._record_metrics(res)
             self._explain_retire_if_drained()
@@ -1315,6 +1330,7 @@ class Scheduler:
             else:
                 self._fail(p, cycle, res, (f"PreFilter:{status.message}",))
         batch = kept
+        trace.end_span(pop_span)
         if not batch:
             # every popped pod failed PreFilter: they still get report
             # rows (status reasons, no device analytics) and the reason

@@ -36,6 +36,7 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Optional
 
+from kubernetes_tpu.obs.trace import annotate
 from kubernetes_tpu.utils.interner import bucket_size
 
 #: the padding grid's smallest bucket (pods_to_device's bucket_size
@@ -192,9 +193,15 @@ class ServingLoop:
     def ingest(self, fn, *args, **kwargs):
         """Run an event-feed callable (scheduler.on_pod_add, ...) under
         the loop's ingest lock — the thread-safe seam for producers
-        living on other threads."""
-        with self.lock:
-            return fn(*args, **kwargs)
+        living on other threads. The wait for the lock and the held
+        section are separate profiler spans."""
+        with annotate("serving:ingest-wait"):
+            self.lock.acquire()
+        try:
+            with annotate("serving:ingest"):
+                return fn(*args, **kwargs)
+        finally:
+            self.lock.release()
 
     def _depth(self) -> int:
         return self.sched.queue.pending_counts()["active"]
@@ -204,7 +211,9 @@ class ServingLoop:
         a cycle ran, else None. Bounded blocking (<= idle_wait_s)."""
         depth = self._depth()
         if depth == 0 and not self.window.open:
-            if not self.bell.wait(self.config.idle_wait_s):
+            with annotate("serving:bell-wait"):
+                rang = self.bell.wait(self.config.idle_wait_s)
+            if not rang:
                 # clean timeout: queue maintenance so parked backoff /
                 # unschedulable pods still resurface; any pod it moves
                 # rings the bell and the next iteration schedules it
@@ -213,12 +222,17 @@ class ServingLoop:
             return None
         dec = self.window.observe(depth)
         if not dec.flush:
-            self.bell.wait(dec.wait_s)
+            with annotate("serving:bell-wait"):
+                self.bell.wait(dec.wait_s)
             return None
         window_s = self.window.close()
-        with self.lock:
+        with annotate("serving:cycle-wait"):
+            self.lock.acquire()
+        try:
             res = self.sched.schedule_cycle(
                 flush_trigger=dec.trigger, window_s=window_s)
+        finally:
+            self.lock.release()
         self.cycles += 1
         m = getattr(self.sched, "metrics", None)
         if m is not None:

@@ -110,8 +110,8 @@ def test_driven_cycles_produce_reconciling_entries():
         # the tolerance must cover the rounding budget or this flakes
         assert phases["solve"] + phases.get("validate", 0) == \
             pytest.approx(rec.spans["solve:batch"], rel=1e-6, abs=2e-6)
-        top_level = (rec.spans["snapshot"] + rec.spans["solve:batch"]
-                     + rec.spans.get("bind", 0.0))
+        top_level = (rec.spans["pop"] + rec.spans["snapshot"]
+                     + rec.spans["solve:batch"] + rec.spans.get("bind", 0.0))
         assert sum(phases.values()) == pytest.approx(
             top_level, rel=1e-6, abs=5e-7 * (len(phases) + 1))
     # rolling distributions exist per (phase, scope, mesh)
