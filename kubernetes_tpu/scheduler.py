@@ -5177,6 +5177,7 @@ class Scheduler:
             "packer_vol_cache": len(packer._vol_cache),
             "packer_vol_pods": len(packer._vol_pods),
             "packer_vec_cache": len(packer._vec_cache),
+            "packer_pod_index": len(packer._uids_by_key),
             "packer_pod_table_memo": len(packer._pod_table_memo),
             "packer_vol_table_memo": len(packer._vol_table_memo),
             # interner dedupe floors: grow with VOCABULARY (distinct

@@ -791,6 +791,14 @@ class SnapshotPacker:
         # per-pod resource vectors (R-dependent; recomputed when the scalar
         # universe grows) feeding the native usage aggregation
         self._vec_cache: Dict[tuple, Tuple[int, np.ndarray, np.ndarray]] = {}
+        #: pod key -> every uid stored under that key in the four per-pod
+        #: caches above (filled by ``_remember_pod``), so forget_pod drops a
+        #: pod's entries without walking the caches. A uid may outlive its
+        #: entries (the volume caches are cleared wholesale); it leaves
+        #: with the pod's forget_pod. Tuples, not sets: a key nearly always
+        #: holds one uid, and a tuple of strings is cheaper to build and
+        #: untracked by the garbage collector.
+        self._uids_by_key: Dict[str, Tuple[str, ...]] = {}
         #: node name -> PV names attached there WITHOUT a live bound pod
         #: using them (the attach-detach controller's actual-state
         #: residue: detach-grace stragglers). These occupy attach-limit
@@ -835,18 +843,31 @@ class SnapshotPacker:
         if rv is None:
             rv = resolve_pod_volumes(pod, self.vol_state)
             self.u.intern_volume_refs(rv)
-            self._vol_cache[key] = rv
+            self._remember_pod(self._vol_cache, key, rv)
         return rv
+
+    def _remember_pod(self, cache: dict, key: tuple, value) -> None:
+        """Store ``value`` under ``key`` = (pod key, uid) in one of the
+        per-pod caches and index the uid by pod key for forget_pod."""
+        cache[key] = value
+        uids = self._uids_by_key.get(key[0])
+        if uids is None:
+            self._uids_by_key[key[0]] = (key[1],)
+        elif key[1] not in uids:
+            self._uids_by_key[key[0]] = uids + (key[1],)
 
     def forget_pod(self, pod_key: str) -> None:
         """Drop per-pod memoization for a deleted pod so churn doesn't grow
         the caches (and set_volume_state doesn't re-resolve dead pods)
-        forever. Universe tokens stay — interners are append-only by design
+        forever: every uid ever stored under the key, through the pod-key
+        index, so a delete costs O(uids of the key), not O(cached pods).
+        Universe tokens stay — interners are append-only by design
         (bucketed widths make stale entries cheap)."""
-        for cache in (self._pod_refs, self._vol_cache, self._vol_pods,
-                      self._vec_cache):
-            for k in [k for k in cache if k[0] == pod_key]:
-                del cache[k]
+        for uid in self._uids_by_key.pop(pod_key, ()):
+            key = (pod_key, uid)
+            for cache in (self._pod_refs, self._vol_cache, self._vol_pods,
+                          self._vec_cache):
+                cache.pop(key, None)
         self._pack_epoch += 1
 
     def _pod_vectors(self, pods: Sequence[Pod], R: int):
@@ -863,7 +884,7 @@ class SnapshotPacker:
                     self.u.resource_vector(p.effective_requests(), R),
                     np.asarray(p.nonzero_requests(), np.float32),
                 )
-                self._vec_cache[ck] = ent
+                self._remember_pod(self._vec_cache, ck, ent)
             req[idx] = ent[1]
             nz[idx] = ent[2]
         return req, nz
@@ -875,10 +896,11 @@ class SnapshotPacker:
         spread_hard, spread_soft) ids, cached per pod identity
         (namespace/name/uid — uid so a deleted-and-recreated pod with
         different spec is re-interned)."""
+        ck = (pod.key(), pod.uid)
         if pod.volumes:
-            self._vol_pods[(pod.key(), pod.uid)] = pod
+            self._remember_pod(self._vol_pods, ck, pod)
             self.resolve_volumes(pod)
-        cached = self._pod_refs.get((pod.key(), pod.uid))
+        cached = self._pod_refs.get(ck)
         if cached is not None:
             return cached
         u = self.u
@@ -906,7 +928,7 @@ class SnapshotPacker:
                 u.image_sizes.append(0.0)
         if pod.owner_uid:
             u.owner_uids.intern(pod.owner_uid)
-        self._pod_refs[(pod.key(), pod.uid)] = refs
+        self._remember_pod(self._pod_refs, ck, refs)
         return refs
 
     def intern_node(self, node: Node) -> int:

@@ -264,6 +264,86 @@ def test_forget_pod_drops_memoized_tables():
     assert pk.pack_pods(pods) is not t1  # epoch bump invalidated
 
 
+_PER_POD_CACHES = ("_pod_refs", "_vol_cache", "_vol_pods", "_vec_cache")
+
+
+def _vol_pod(name, uid):
+    from kubernetes_tpu.api.types import PodVolume
+
+    return dataclasses.replace(
+        make_pod(name, cpu_milli=1), uid=uid,
+        volumes=(PodVolume(pvc=f"claim-{name}"),))
+
+
+#: each per-pod cache filled through its own insert site alone, so a
+#: site that stores without indexing the uid is not masked by another
+_FILL_ONE_CACHE = {
+    "_pod_refs": lambda pk, p: pk.intern_pod(
+        dataclasses.replace(p, volumes=())),
+    "_vol_pods": lambda pk, p: pk.intern_pod(p),
+    "_vol_cache": lambda pk, p: pk.resolve_volumes(p),
+    "_vec_cache": lambda pk, p: pk._pod_vectors([p], pk.u.n_resources()),
+}
+
+
+@pytest.mark.parametrize("cache_name", _PER_POD_CACHES)
+def test_forget_pod_drops_every_uid_of_the_key(cache_name):
+    """A pod deleted and recreated under the same key leaves entries for
+    both uids; forget_pod drops them all from each cache through the
+    pod-key index, leaves other pods' entries alone, leaves no index
+    entry for the key, and bumps the pack epoch once."""
+    pk = SchedulerCache().packer
+    first, again = _vol_pod("churn", "uid-a"), _vol_pod("churn", "uid-b")
+    others = [_vol_pod(f"keep{i}", f"uid-k{i}") for i in range(3)]
+    for p in (first, again, *others):
+        _FILL_ONE_CACHE[cache_name](pk, p)
+    cache = getattr(pk, cache_name)
+    key = first.key()
+    assert {k for k in cache if k[0] == key} == {
+        (key, "uid-a"), (key, "uid-b")}
+    kept = {k: v for k, v in cache.items() if k[0] != key}
+    assert len(kept) == len(others)
+    epoch = pk._pack_epoch
+
+    pk.forget_pod(key)
+
+    assert not [k for k in cache if k[0] == key]
+    assert dict(cache) == kept
+    assert key not in pk._uids_by_key
+    assert all(o.key() in pk._uids_by_key for o in others)
+    assert pk._pack_epoch == epoch + 1
+
+
+class _UnwalkableDict(dict):
+    """A dict that answers lookups and pops but refuses to be walked."""
+
+    def _walked(self, *a, **kw):
+        raise AssertionError("a per-pod cache was walked")
+
+    __iter__ = keys = items = values = _walked
+
+
+def test_forget_pod_never_walks_a_cache():
+    """Structural guard, no timing: with 1,000 pods packed and every
+    per-pod cache replaced by a dict that raises when walked, forget_pod
+    still drops the pod's entries — it reaches them by key alone."""
+    pk = SchedulerCache().packer
+    pods = [dataclasses.replace(_vol_pod(f"w{i}", f"uid-w{i}"),
+                                node_name="n0") for i in range(1000)]
+    pk.pack_nodes([make_node("n0")], pods)  # interns: all four caches
+    for name in _PER_POD_CACHES:
+        assert len(getattr(pk, name)) == 1000, name
+        setattr(pk, name, _UnwalkableDict(dict.items(getattr(pk, name))))
+    victim = pods[500].key()
+    pk.forget_pod(victim)
+    pk.forget_pod("default/never-seen")
+    for name in _PER_POD_CACHES:
+        cache = getattr(pk, name)
+        assert len(cache) == 999, name
+        assert (victim, "uid-w500") not in cache, name
+    assert victim not in pk._uids_by_key
+
+
 def test_scheduler_uses_resident_snapshot_across_cycles():
     """Driver integration: cycle 1 uploads full; an idle-state cycle 2
     with new pods only reuses/patches (assume effects dirty exactly the

@@ -272,7 +272,7 @@ def test_pod_side_state_returns_to_baseline_on_exit():
     sizes = s.state_sizes()
     for key in ("why_pending", "ambiguous_binds", "cycle_states",
                 "waiting_pods", "queue_pending", "cache_assumed",
-                "cache_pods", "packer_pod_refs"):
+                "cache_pods", "packer_pod_refs", "packer_pod_index"):
         assert sizes[key] == 0, (key, sizes)
 
 
