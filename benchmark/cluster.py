@@ -4,8 +4,27 @@ Copied from the program's scheduler_perf fixtures (``models/cluster.py``
 base node and base pod, ``chip_smoke.constraint_feed``) so that a later
 change to the program's generators cannot change the benchmark's inputs.
 Every seed gets the same sizes and the same counts (nodes per zone,
-tainted nodes, pods per service, pods per preferred zone); the seed only
-chooses the order in which they are dealt.
+tainted nodes, pods per service, pods per preferred zone, existing pods
+per node and per controller); the seed only chooses the order in which
+they are dealt.
+
+Keys of a configuration's ``cluster`` beyond the first benchmark's, each
+optional (absent, :func:`build` makes what it made before them, draw
+for draw):
+
+- ``existing_pod``: ``{cpu_milli, memory_bytes}`` of the existing pods;
+  default ``pod``.
+- ``existing_controllers``: ``{count, name, labels}``: the existing pods
+  are dealt over ``count`` ReplicationControllers, equal counts, by a
+  seed permutation drawn after every other draw. A pod of controller
+  ``c`` is labelled ``name: <name>-<c>`` plus ``labels``, points at the
+  controller's uid and spreads by the selector ``name: <name>-<c>``.
+  Default: no controller, no labels.
+- ``pod_controller``: ``"none"`` (default) or ``"own"``: every pool slot
+  is the only replica of a controller of its own (labelled ``name: <slot
+  name>``, spread by that selector). Not with ``services``: a pod of a
+  service and a controller spreads by both selectors at once, which the
+  reference does not implement.
 """
 
 from __future__ import annotations
@@ -33,6 +52,21 @@ class Cluster:
     existing_node: np.ndarray  # (E,) node index of each existing pod
     pod_cpu: float
     pod_mem: float
+    existing_cpu: float
+    existing_mem: float
+    #: (E,) controller of each existing pod, -1 = none
+    existing_controller: np.ndarray
+    n_controllers: int  # existing pods' controllers
+    own_controller: bool  # pod_controller "own"
+
+    def slot_owner(self, s: int) -> int:
+        """The owner of pool slot ``s`` in one numbering with the existing
+        pods' controllers (``0 .. n_controllers-1``): then the services,
+        or one controller per slot; -1 = none."""
+        if self.own_controller:
+            return self.n_controllers + s
+        svc = int(self.slot_service[s % len(self.slot_service)])
+        return self.n_controllers + svc if svc >= 0 else -1
 
 
 def _rng(seed: int) -> np.random.Generator:
@@ -43,6 +77,7 @@ def build(config: dict, seed: int, n_slots: int) -> Cluster:
     """Nodes, existing pods and per-slot pod attributes."""
     from kubernetes_tpu.api.types import (
         EFFECT_PREFER_NO_SCHEDULE,
+        LabelSelector,
         Node,
         Pod,
         Resources,
@@ -69,21 +104,46 @@ def build(config: dict, seed: int, n_slots: int) -> Cluster:
                                                 memory=nd["memory_bytes"],
                                                 pods=nd["pods"])))
     pod = c["pod"]
+    epod = c.get("existing_pod", pod)
     n_exist = int(c["existing_pods"])
     existing_node = rng.permutation(n)[np.arange(n_exist) % n]
-    existing = [Pod(name=f"existing-{i}", node_name=f"node-{existing_node[i]}",
-                    requests=Resources(cpu_milli=pod["cpu_milli"],
-                                       memory=pod["memory_bytes"]))
-                for i in range(n_exist)]
     services = int(c["services"])
     slot_service = (rng.permutation(n_slots) % services if services
                     else np.full(n_slots, -1))
     slot_zone_pref = (rng.permutation(n_slots) % zones
                       if c["zone_affinity_weight"] and zones
                       else np.full(n_slots, -1))
+    own = c.get("pod_controller", "none")
+    if own not in ("none", "own"):
+        raise ValueError(f"pod_controller {own!r}: 'none' or 'own'")
+    if own == "own" and services:
+        raise ValueError("pod_controller 'own' with services: a pod would "
+                         "spread by two selectors at once")
+    ctl = c.get("existing_controllers", {"count": 0})
+    k = int(ctl["count"])
+    existing_controller = (rng.permutation(n_exist) % k if k
+                           else np.full(n_exist, -1))
+    sels = [LabelSelector(match_labels={"name": f"{ctl['name']}-{j}"})
+            for j in range(k)]
+    existing = []
+    for i in range(n_exist):
+        kw = {}
+        j = int(existing_controller[i])
+        if j >= 0:
+            kw = {"labels": {**ctl.get("labels", {}),
+                             "name": f"{ctl['name']}-{j}"},
+                  "owner_uid": f"rc-{ctl['name']}-{j}",
+                  "spread_selectors": (sels[j],)}
+        existing.append(Pod(name=f"existing-{i}",
+                            node_name=f"node-{existing_node[i]}",
+                            requests=Resources(cpu_milli=epod["cpu_milli"],
+                                               memory=epod["memory_bytes"]),
+                            **kw))
     return Cluster(nodes, existing, node_zone, node_tainted, slot_service,
                    slot_zone_pref, existing_node, float(pod["cpu_milli"]),
-                   float(pod["memory_bytes"]))
+                   float(pod["memory_bytes"]), float(epod["cpu_milli"]),
+                   float(epod["memory_bytes"]), existing_controller, k,
+                   own == "own")
 
 
 class PodPool:
@@ -127,6 +187,7 @@ class PodPool:
         cl = self.cluster
         svc = int(cl.slot_service[s % len(cl.slot_service)])
         zp = int(cl.slot_zone_pref[s % len(cl.slot_zone_pref)])
+        name = f"{self.prefix}-slot{s}"
         forms = []
         for _ in range(2):
             kw = {}
@@ -134,13 +195,18 @@ class PodPool:
                 kw["labels"] = {"app": f"svc-{svc}"}
                 kw["spread_selectors"] = (
                     LabelSelector(match_labels={"app": f"svc-{svc}"}),)
+            if cl.own_controller:
+                kw["labels"] = {"name": name}
+                kw["owner_uid"] = f"rc-{name}"
+                kw["spread_selectors"] = (
+                    LabelSelector(match_labels={"name": name}),)
             if zp >= 0:
                 kw["affinity"] = Affinity(node_preferred=(
                     PreferredSchedulingTerm(
                         weight=int(c["zone_affinity_weight"]),
                         preference=NodeSelectorTerm((Requirement(
                             ZONE_KEY, "In", (f"zone-{zp}",)),))),))
-            forms.append(Pod(name=f"{self.prefix}-slot{s}",
+            forms.append(Pod(name=name,
                              requests=Resources(cpu_milli=cl.pod_cpu,
                                                 memory=cl.pod_mem), **kw))
         self.pending.append(forms[0])

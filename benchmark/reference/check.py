@@ -43,7 +43,7 @@ LIMITS = {
     "infeasible": 0,
     "false_unschedulable": 0,
     "unanswered": 0,
-    "score_regret": 2.5,
+    "score_regret": 1.5,
 }
 
 
@@ -79,49 +79,39 @@ class Replay:
         self.nodes = node_arrays(cl)
         N = len(cl.nodes)
         self.n_zones = int(max(cl.node_zone.max(initial=-1) + 1, 1))
-        n_svc = int(config["cluster"]["services"])
-        self.state = S.State(np.zeros(N), np.zeros(N), np.zeros(N),
-                             np.zeros((n_svc, N)) if n_svc else None)
-        self.pod_cpu, self.pod_mem = cl.pod_cpu, cl.pod_mem
+        self.state = S.State(np.zeros(N), np.zeros(N), np.zeros(N))
         self.zone_weight = int(config["cluster"]["zone_affinity_weight"])
         self.node_index = {n.name: i for i, n in enumerate(cl.nodes)}
         self.weights = dict(config["priorities"])
         self.prefill = dict(log.prefill)
         S.check_supported(log.pool.pending + cl.existing, cl.nodes)
-        for i in cl.existing_node:
-            self._add(int(i), -1, 1)
+        for node, owner in zip(cl.existing_node.tolist(),
+                               cl.existing_controller.tolist()):
+            self.state.add(node, cl.existing_cpu, cl.existing_mem, owner)
         for inc, node in log.prefill:
-            self._add(node, self.service(inc), 1)
+            self._add(inc, node, 1)
 
     def spec(self, inc: int) -> S.PodSpec:
         cl = self.log.cluster
         s = self.log.pool.slot_of[inc]
-        return S.PodSpec(self.pod_cpu, self.pod_mem,
-                         int(cl.slot_service[s % len(cl.slot_service)]),
+        return S.PodSpec(cl.pod_cpu, cl.pod_mem, cl.slot_owner(s),
                          int(cl.slot_zone_pref[s % len(cl.slot_zone_pref)]),
                          self.zone_weight)
 
-    def service(self, inc: int) -> int:
-        cl = self.log.cluster
-        s = self.log.pool.slot_of[inc]
-        return int(cl.slot_service[s % len(cl.slot_service)])
-
-    def _add(self, node: int, svc: int, sign: int) -> None:
-        st = self.state
-        st.cpu[node] += sign * self.pod_cpu
-        st.mem[node] += sign * self.pod_mem
-        st.pods[node] += sign
-        if svc >= 0 and st.svc is not None:
-            st.svc[svc, node] += sign
+    def _add(self, inc: int, node: int, sign: int) -> None:
+        sp = self.spec(inc)
+        self.state.add(node, sp.cpu, sp.mem, sp.owner, sign)
 
 
 def check(log, config: dict, traffic: dict, seed: int,
           limits: dict = LIMITS, control=None) -> dict:
     """Returns ``{number: (value, limit)}`` and the ``correct`` verdict
-    under ``"correct"``. ``control(rp, start, incs)`` -> ``(placed, end)``
-    puts another placer in the program's place on the sampled cycles:
+    under ``"correct"``. ``control(rp, start, incs, prev)`` -> ``(placed,
+    end)`` puts another placer in the program's place on the sampled
+    cycles (``prev``: the state at the previous cycle's start, or None):
     its placements of the same pods from the same start are the ones
-    judged for ``score_regret`` (the lower-precision control)."""
+    judged for ``score_regret`` and ``over_allocatable`` (the control,
+    ``reference/control.py``)."""
     rp = Replay(log, config)
     pool = log.pool
     rng = np.random.default_rng(int(seed) % (2**63) + 7)
@@ -140,6 +130,7 @@ def check(log, config: dict, traffic: dict, seed: int,
     n_regret = 0
     deletes = log.deletes
     di = 0
+    start = None
     nodes = log.cluster.nodes
     for ci, cyc in enumerate(log.cycles):
         while di < len(deletes) and deletes[di][1] <= cyc.bind_lo:
@@ -148,9 +139,11 @@ def check(log, config: dict, traffic: dict, seed: int,
             if node is None:
                 node = rp.prefill.get(inc)
             if node is not None:
-                rp._add(node, rp.service(inc), -1)
+                rp._add(inc, node, -1)
             di += 1
-        start = rp.state.copy() if ci in sampled else None
+        prev = start if control is not None else None
+        start = (rp.state.copy() if ci in sampled or control is not None
+                 else None)
         touched = []
         placed = []
         for k2 in range(cyc.bind_lo, cyc.bind_hi):
@@ -164,26 +157,22 @@ def check(log, config: dict, traffic: dict, seed: int,
                 infeasible += 1
                 continue
             bound_at[inc] = node
-            rp._add(node, rp.service(inc), 1)
+            rp._add(inc, node, 1)
             touched.append(node)
             placed.append((inc, node))
         st = rp.state
-        t = np.asarray(touched, np.int64)
-        if len(t):
-            bad = t[(st.cpu[t] > rp.nodes.cap_cpu[t])
-                    | (st.mem[t] > rp.nodes.cap_mem[t])
-                    | (st.pods[t] > rp.nodes.cap_pods[t])]
-            over.update(bad.tolist())
+        over.update(_over(rp, st, touched))
         for key in cyc.failed:
             inc = pool.inc_of(key)
             if S.fits(rp.spec(inc), rp.nodes, st).any():
                 false_unsched += 1
-        if start is not None and placed:
+        if ci in sampled and placed:
             judged, end = placed, st
             if control is not None:
                 incs = [i for i, _ in placed] + [pool.inc_of(k)
                                                  for k in cyc.failed]
-                judged, end = control(rp, start, incs)
+                judged, end = control(rp, start, incs, prev)
+                over.update(_over(rp, end, [n for _, n in judged]))
             pick = rng.choice(len(judged), size=min(per_cycle, len(judged)),
                               replace=False)
             for j in pick:
@@ -214,20 +203,23 @@ def check(log, config: dict, traffic: dict, seed: int,
     return out
 
 
+def _over(rp: Replay, st: S.State, touched) -> list:
+    """The nodes of ``touched`` over cpu, memory or pod count."""
+    t = np.asarray(touched, np.int64)
+    n = rp.nodes
+    return t[(st.cpu[t] > n.cap_cpu[t]) | (st.mem[t] > n.cap_mem[t])
+             | (st.pods[t] > n.cap_pods[t])].tolist()
+
+
 def placement_regret(rp: Replay, start: S.State, end: S.State, inc: int,
                      node: int) -> float:
     """How far a node that still fits the pod at the cycle's end is sure
     to outscore the chosen ``node`` (<= 0: consistent with an argmax at
     some round's usage)."""
     spec = rp.spec(inc)
-    svc = spec.service
     # the chosen node's usage when the pod was scored excluded the pod
-    own_hi = end.copy()
-    own_hi.cpu[node] -= spec.cpu
-    own_hi.mem[node] -= spec.mem
-    own_hi.pods[node] -= 1
-    if svc >= 0 and own_hi.svc is not None:
-        own_hi.svc[svc, node] -= 1
+    own_hi = end.copy(spec.owner)
+    own_hi.add(node, spec.cpu, spec.mem, spec.owner, -1)
     lo, _, _, f_hi = S.bounds(spec, rp.nodes, start, end, rp.weights,
                               rp.n_zones)
     _, hi_own, _, _ = S.bounds(spec, rp.nodes, start, own_hi, rp.weights,
@@ -237,17 +229,6 @@ def placement_regret(rp: Replay, start: S.State, end: S.State, inc: int,
     if not f_hi.any():
         return -np.inf
     return float(lo[f_hi].max() - hi_own[node])
-
-
-def _without(st: S.State, spec: S.PodSpec, node: int, n: int = 1,
-             svc: bool = True) -> S.State:
-    out = st.copy()
-    out.cpu[node] -= n * spec.cpu
-    out.mem[node] -= n * spec.mem
-    out.pods[node] -= n
-    if svc and spec.service >= 0 and out.svc is not None:
-        out.svc[spec.service, node] -= n
-    return out
 
 
 def node_regret(rp: Replay, start: S.State, end: S.State, node: int,
@@ -260,29 +241,28 @@ def node_regret(rp: Replay, start: S.State, end: S.State, node: int,
     k = len(incs)
     if k <= cap:
         return -np.inf
+    specs = [rp.spec(inc) for inc in incs]
     best = np.inf
     seen = set()
-    for inc in incs:
-        spec = rp.spec(inc)
-        key = (spec.service, spec.zone_pref)
+    for j, spec in enumerate(specs):
+        key = (spec.cpu, spec.mem, spec.owner, spec.zone_pref)
         if key in seen:
             continue
         seen.add(key)
-        # usage of ``node`` when the last pod was scored: at least
-        # k - cap pods of this cycle (all pods request the same), at
-        # most every other pod; service counts anywhere in between
-        floor = _without(end, spec, node, cap, svc=False)
-        floor.svc = None if start.svc is None else start.svc.copy()
-        floor.cpu, floor.mem, floor.pods = (
-            np.maximum(floor.cpu, start.cpu), np.maximum(floor.mem, start.mem),
-            np.maximum(floor.pods, start.pods))
+        # usage of ``node`` when this pod was scored, had it come last:
+        # at least the k - cap smallest requests of the others (resource
+        # by resource, as the shapes may differ), at most every other
+        # pod; owner counts anywhere in between
+        others = specs[:j] + specs[j + 1:]
+        lo_st = start.copy(spec.owner)
+        lo_st.cpu[node] += sum(sorted(o.cpu for o in others)[:k - cap])
+        lo_st.mem[node] += sum(sorted(o.mem for o in others)[:k - cap])
+        lo_st.pods[node] += k - cap
+        hi_st = end.copy(spec.owner)
+        hi_st.add(node, spec.cpu, spec.mem, spec.owner, -1)
         lo_oth, _, _, f_hi = S.bounds(spec, rp.nodes, start, end, rp.weights,
                                       rp.n_zones)
-        lo_st = start.copy()
-        lo_st.cpu[node], lo_st.mem[node], lo_st.pods[node] = (
-            floor.cpu[node], floor.mem[node], floor.pods[node])
-        _, hi_n, _, _ = S.bounds(spec, rp.nodes, lo_st,
-                                 _without(end, spec, node), rp.weights,
+        _, hi_n, _, _ = S.bounds(spec, rp.nodes, lo_st, hi_st, rp.weights,
                                  rp.n_zones)
         f_hi = f_hi.copy()
         f_hi[node] = False

@@ -8,6 +8,12 @@ places a whole cycle's pods in one round (:func:`one_round`), each on its
 argmax at the cycle's start usage, scores never refreshed as the batch
 lands: the shortcut that would tempt a later PR cutting the rounds.
 
+Where a node fits no more of a cycle's pods than ``per_node_cap`` (a
+deployment whose nodes are nearly full, or cycles of a few pods), one
+round is a placement the guarantee allows, and :func:`stale_snapshot`
+is the control: each pod placed by a snapshot one cycle old, the step
+that would tempt a PR that skips the per-cycle snapshot.
+
 On each sampled cycle of a real run at the cell's own size, a placer
 puts the same pods from the same start state; the output check judges
 those placements exactly as it judges the program's.
@@ -15,7 +21,7 @@ those placements exactly as it judges the program's.
     python3 benchmark/reference/control.py --workload <cell> --seeds 1,2,3 --seconds 5
 
 prints one JSON line per seed with the numbers of the program and of
-the control (``one_round``).
+each control (``one_round``, ``stale_snapshot``).
 """
 
 from __future__ import annotations
@@ -38,20 +44,20 @@ from reference.check import check  # noqa: E402
 
 
 def one_round():
-    """``control(rp, start, incs) -> (placed, end)``: the control. Each pod
+    """``control(rp, start, incs, prev) -> (placed, end)``: the control. Each pod
     goes to its top-scored node at the cycle's start usage, scores never
     refreshed as the batch lands (a one-round solve), the next-best node
     when that one is full. Scores in float32, all nodes, lowest index on
     ties."""
     import jax.numpy as jnp
 
-    def place(rp, start, incs):
+    def place(rp, start, incs, prev):
         st = start.copy()
         placed = []
         order_of = {}
         for inc in incs:
             spec = rp.spec(inc)
-            key = (spec.service, spec.zone_pref)
+            key = (spec.cpu, spec.mem, spec.owner, spec.zone_pref)
             order = order_of.get(key)
             if order is None:
                 s = np.asarray(S.exact(spec, rp.nodes, start, rp.weights,
@@ -66,13 +72,38 @@ def one_round():
             else:
                 continue
             node = int(node)
-            st.cpu[node] += spec.cpu
-            st.mem[node] += spec.mem
-            st.pods[node] += 1
-            if spec.service >= 0 and st.svc is not None:
-                st.svc[spec.service, node] += 1
+            st.add(node, spec.cpu, spec.mem, spec.owner)
             placed.append((inc, node))
         return placed, st
+
+    return place
+
+
+def stale_snapshot():
+    """``control(rp, start, incs, prev) -> (placed, end)``: each pod, one
+    at a time as v1.16 schedules, on its top-scored node (float32, all
+    nodes, lowest index on ties) by the usage at the previous cycle's
+    start, updated only by its own placements. Breaks "a top-scored node
+    at the usage of its round" where the previous cycle's bindings move
+    the order, and "no node over allocatable" where they filled a node
+    the old snapshot still shows room on."""
+    import jax.numpy as jnp
+
+    def place(rp, start, incs, prev):
+        seen = (start if prev is None else prev).copy()
+        end = start.copy()
+        placed = []
+        for inc in incs:
+            spec = rp.spec(inc)
+            s = np.asarray(S.exact(spec, rp.nodes, seen, rp.weights,
+                                   rp.n_zones, jnp.float32))
+            if not np.isfinite(s).any():
+                continue
+            node = int(np.argmax(s))
+            seen.add(node, spec.cpu, spec.mem, spec.owner)
+            end.add(node, spec.cpu, spec.mem, spec.owner)
+            placed.append((inc, node))
+        return placed, end
 
     return place
 
@@ -101,7 +132,8 @@ def main(argv=None) -> int:
         log, sched, _ = runner(config, traffic, seed, args.seconds, None)
         del sched
         out = {"seed": seed, "workload": args.workload}
-        for name, ctl in (("program", None), ("one_round", one_round())):
+        for name, ctl in (("program", None), ("one_round", one_round()),
+                          ("stale_snapshot", stale_snapshot())):
             res = check(log, config, traffic, seed, control=ctl)
             out[name] = {k: (v[0] if isinstance(v, tuple) else v)
                          for k, v in res.items()}

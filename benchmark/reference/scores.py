@@ -23,8 +23,7 @@ inputs where they are not.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Optional
+from dataclasses import dataclass, field
 
 import numpy as np
 
@@ -46,30 +45,68 @@ class Nodes:
 @dataclass
 class State:
     """Usage of every node: requested cpu/mem (non-zero requests, as the
-    priorities count them), pod count, and per-service pod counts."""
+    priorities count them), pod count, and the pods of each owner (the
+    Service or controller whose selector SelectorSpread counts) on each
+    node, kept sparse as ``{owner: {node: pods}}``: a deployment with
+    thousands of single-replica controllers would make a dense
+    (owners, N) copy of each judged state hundreds of megabytes."""
 
     cpu: np.ndarray
     mem: np.ndarray
     pods: np.ndarray
-    svc: Optional[np.ndarray]  # (services, N) or None
+    owners: dict = field(default_factory=dict)
 
-    def copy(self) -> "State":
+    def copy(self, owner: int | None = None) -> "State":
+        """A copy; given ``owner``, one that keeps that owner's counts
+        alone, which is all that a score of its pod reads."""
+        rows = (self.owners if owner is None
+                else {owner: self.owners[owner]} if owner in self.owners
+                else {})
         return State(self.cpu.copy(), self.mem.copy(), self.pods.copy(),
-                     None if self.svc is None else self.svc.copy())
+                     {o: dict(r) for o, r in rows.items()})
+
+    def add(self, node: int, cpu: float, mem: float, owner: int,
+            n: int = 1) -> None:
+        """``n`` pods of one shape and owner (-1: none) onto ``node``
+        (off it, for ``n < 0``)."""
+        self.cpu[node] += n * cpu
+        self.mem[node] += n * mem
+        self.pods[node] += n
+        if owner >= 0:
+            row = self.owners.setdefault(owner, {})
+            c = row.get(node, 0) + n
+            if c:
+                row[node] = c
+            else:
+                del row[node]
+                if not row:
+                    del self.owners[owner]
+
+    def owner_counts(self, owner: int) -> np.ndarray:
+        """(N,) pods of ``owner`` on each node."""
+        out = np.zeros(len(self.cpu))
+        row = self.owners.get(owner)
+        if row:
+            out[list(row)] = list(row.values())
+        return out
 
 
 @dataclass
 class PodSpec:
     cpu: float
     mem: float
-    service: int  # -1 = no SelectorSpread owner
+    owner: int  # -1 = no SelectorSpread owner
     zone_pref: int  # -1 = no preferred zone
     zone_weight: int
 
 
 def check_supported(pods, nodes) -> None:
     """Refuse host objects whose other default priorities would differ
-    between nodes (the reference does not implement them)."""
+    between nodes (the reference does not implement them). A pod's
+    controller (``owner_uid``) is fine while no node has avoid-pods
+    annotations: NodePreferAvoidPods is then the same on every node. A
+    pod spreads by one selector at most: v1.16 counts the pods matching
+    all of them, which the reference's one owner per pod does not."""
     for n in nodes:
         if n.images or n.prefer_avoid_owner_uids:
             raise ValueError(f"reference: node {n.name} has images or "
@@ -79,7 +116,8 @@ def check_supported(pods, nodes) -> None:
         if (a.pod_affinity_required or a.pod_affinity_preferred
                 or a.pod_anti_affinity_required
                 or a.pod_anti_affinity_preferred or p.images
-                or p.topology_spread or p.tolerations):
+                or p.topology_spread or p.tolerations
+                or len(p.spread_selectors) > 1):
             raise ValueError(f"reference: pod {p.name} uses a feature the "
                              "reference does not implement")
 
@@ -190,12 +228,12 @@ def bounds(spec: PodSpec, nodes: Nodes, lo: State, hi: State,
         s_hi += w * np.maximum(a, b)
     w = weights.get("SelectorSpreadPriority", 0)
     if w:
-        if spec.service < 0 or lo.svc is None:
+        if spec.owner < 0:
             s_lo += w * MAX_PRIORITY
             s_hi += w * MAX_PRIORITY
         else:
-            c_lo = lo.svc[spec.service].astype(np.float64)
-            c_hi = hi.svc[spec.service].astype(np.float64)
+            c_lo = lo.owner_counts(spec.owner)
+            c_hi = hi.owner_counts(spec.owner)
             have = bool((nodes.zone >= 0).any())
             maxn_lo = c_lo[f_hi].max(initial=0)
             maxn_hi = c_hi[f_lo].max(initial=0)
@@ -220,8 +258,8 @@ def exact(spec: PodSpec, nodes: Nodes, st: State, weights: dict,
     n = len(nodes.cap_cpu)
     na = (np.where(nodes.zone == spec.zone_pref, spec.zone_weight, 0)
           if spec.zone_pref >= 0 else np.zeros(n))
-    if spec.service >= 0 and st.svc is not None:
-        c = st.svc[spec.service].astype(np.float64)
+    if spec.owner >= 0:
+        c = st.owner_counts(spec.owner)
         z = _zone_sums(c, nodes.zone, n_zones, feas & (nodes.zone >= 0))
         zc = z[np.maximum(nodes.zone, 0)]
         spread = 1

@@ -37,6 +37,64 @@ TINY_TRAFFIC = {
 }
 
 
+#: a deployment shaped as the v1.16 density test (test/e2e/scalability/
+#: density.go): existing pods at 30 per node and 1/100 of a node, owned by
+#: ReplicationControllers, and traffic pods at 1/5 of a node, each the
+#: only replica of its own controller. 48 such nodes hold 3 traffic pods
+#: each, 144 in all; the mixes keep 48 bound, one per node as the test's
+#: 5,000 latency pods on 5,000 nodes, and the backlog 64 pending (its
+#: warmup samples 64 of them).
+DENSITY_CLUSTER = {
+    "nodes": 48, "zones": 4, "existing_pods": 1440,
+    "existing_pod": {"cpu_milli": 40, "memory_bytes": 343597383},
+    "existing_controllers": {"count": 3, "name": "density1440",
+                             "labels": {"type": "densityPod"}},
+    "pod": {"cpu_milli": 800, "memory_bytes": 6871947673},
+    "pod_controller": "own",
+}
+DENSITY_TRAFFIC = {
+    "backlog": {"pending": 64, "max_bound": 48, "prefill_bound": 48,
+                "warm_pod_buckets": [64, 128]},
+    "open": {"rate_pods_s": 60, "max_bound": 48, "prefill_bound": 48,
+             "warm_bursts": [8, 16]},
+}
+
+
+def add_density(root: str) -> None:
+    """A density-shaped configuration and a mix of each kind, added to a
+    checkout root as new files and entries alone: cells
+    ``density.backlog`` and ``density.open``."""
+    b = os.path.join(root, "benchmark")
+    with open(os.path.join(b, "configs", "sp5k-base.json")) as f:
+        cfg = json.load(f)
+    cfg["name"] = "density"
+    cfg["cluster"].update(DENSITY_CLUSTER)
+    with open(os.path.join(b, "configs", "density.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": "density", "source": "test",
+                            "file": "benchmark/configs/density.json",
+                            "reduced": ["nodes", "existing_pods"],
+                            "why": "test"})
+    for kind, over in DENSITY_TRAFFIC.items():
+        with open(os.path.join(b, "traffic", f"{kind}.json")) as f:
+            t = json.load(f)
+        t.update(over, name=f"density-{kind}")
+        with open(os.path.join(b, "traffic", f"density-{kind}.json"),
+                  "w") as f:
+            json.dump(t, f)
+        cell = f"density.{kind}"
+        spec["workloads"].append({"name": cell, "config": "density",
+                                  "traffic": f"density-{kind}", "chips": 1,
+                                  "why": "test"})
+        for m in spec["end_to_end"] + spec["per_layer"]:
+            if f"sp5k-base.{kind}" in m.get("workloads", ()):
+                m["workloads"].append(cell)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+
+
 def make_root(dst: str) -> str:
     """A checkout root with tiny configurations and mixes."""
     shutil.copytree(BENCH, os.path.join(dst, "benchmark"),
@@ -83,4 +141,6 @@ def make_root(dst: str) -> str:
 
 @pytest.fixture(scope="session")
 def tiny_root(tmp_path_factory):
-    return make_root(str(tmp_path_factory.mktemp("bench_root")))
+    root = make_root(str(tmp_path_factory.mktemp("bench_root")))
+    add_density(root)
+    return root

@@ -11,7 +11,7 @@ import time
 
 import pytest
 
-from conftest import BENCH, REPO, make_root
+from conftest import BENCH, REPO, add_density, make_root
 
 import run
 
@@ -58,16 +58,21 @@ def test_tiny_cell_is_correct_and_reports_its_metrics(tiny_root, cell):
     assert out["checks"]["regret_samples"]["value"] > 0
 
 
+def _files(root):
+    out = {}
+    for dp, _, fs in os.walk(os.path.join(root, "benchmark")):
+        for f in fs:
+            p = os.path.join(dp, f)
+            out[p] = open(p, "rb").read()
+    return out
+
+
 def test_new_config_traffic_and_metric_need_no_edit(tmp_path):
     """A throwaway configuration, traffic mix and per-layer metric, added
     as new files and entries in a copy of BENCHMARK.json, are found by
     name: no existing file changes."""
     root = make_root(str(tmp_path))
-    before = {}
-    for dp, _, fs in os.walk(os.path.join(root, "benchmark")):
-        for f in fs:
-            p = os.path.join(dp, f)
-            before[p] = open(p, "rb").read()
+    before = _files(root)
     b = os.path.join(root, "benchmark")
     cfg = json.load(open(os.path.join(b, "configs", "sp5k-base.json")))
     cfg["name"] = "throwaway"
@@ -98,6 +103,22 @@ def test_new_config_traffic_and_metric_need_no_edit(tmp_path):
     out = tiny(root, "throwaway.mix", trace=True)
     assert out["correct"], out["checks"]
     assert out["metrics"]["throwaway_cycles"]["value"] > 0
+    for p, data in before.items():
+        assert open(p, "rb").read() == data, f"{p} was edited"
+
+
+@pytest.mark.parametrize("cell", ["density.backlog", "density.open"])
+def test_density_config_needs_no_edit(tmp_path, cell):
+    """A deployment shaped as the v1.16 density test (controller-owned
+    existing pods at 30 per node, traffic pods of another shape, each its
+    own controller's only replica) is a configuration file, mixes and
+    entries alone, and its runs are correct."""
+    root = make_root(str(tmp_path))
+    before = _files(root)
+    add_density(root)
+    out = tiny(root, cell, seed=2**31 + 11)
+    assert out["correct"], out["checks"]
+    assert out["checks"]["regret_samples"]["value"] > 0
     for p, data in before.items():
         assert open(p, "rb").read() == data, f"{p} was edited"
 
@@ -138,16 +159,40 @@ def test_broken_timed_path_is_not_correct(tiny_root, monkeypatch, fault, cell):
     assert not out["correct"], out["checks"]
 
 
-@pytest.mark.parametrize("cell", ["sp5k-base.backlog",
-                                  "prefs.backlog",
-                                  "sp5k-base.open"])
-def test_control_is_not_correct_and_the_program_is(tiny_root, cell):
+@pytest.mark.parametrize("cell,fault", [
+    ("density.backlog", "unchanged"), ("density.backlog", "half"),
+    ("density.open", "unchanged"), ("density.open", "half"),
+    ("density.open", "altered")])
+@pytest.mark.parametrize("seed", [17, 19])
+def test_broken_timed_path_is_not_correct_on_density(tiny_root, monkeypatch,
+                                                     cell, fault, seed):
+    """The same faults on the density shape. Each of its nodes holds what
+    every other holds, so a backlog cycle spread over them and moved one
+    node on is a spread as good (every sampled pod reads a regret of -4):
+    no fault, and not listed. A few open-loop pods moved onto a node that
+    holds one more traffic pod read 2 (seed 19) or 4 (seed 18)."""
+    _patch_solve(monkeypatch, fault)
+    out = tiny(tiny_root, cell, seed=seed)
+    assert not out["correct"], out["checks"]
+
+
+@pytest.mark.parametrize("cell,control", [
+    ("sp5k-base.backlog", "one_round"),
+    ("prefs.backlog", "one_round"),
+    ("sp5k-base.open", "one_round"),
+    ("density.open", "stale_snapshot")])
+def test_control_is_not_correct_and_the_program_is(tiny_root, cell, control):
     """The control (reference/control.py) at a size a test run holds: the
-    reference placing each cycle in one round fails the check on three
-    seeds; the same runs' program placements pass."""
+    reference placing each cycle in one round, or (where a node fits no
+    more of a cycle's pods than one round may put there) by a snapshot one
+    cycle old, fails the check on three seeds; the same runs' program
+    placements pass. Not on ``density.backlog``: each cycle there replaces
+    every traffic pod of the last on nodes that all hold the same, so
+    neither control's placements are worse than the program's (both read
+    -1 or -4); the density deployment is an open loop."""
     import driver
+    from reference import control as ctl
     from reference.check import check
-    from reference.control import one_round
 
     spec = run.load_spec(tiny_root)
     _, config, traffic = run.resolve(spec, cell, tiny_root)
@@ -158,6 +203,7 @@ def test_control_is_not_correct_and_the_program_is(tiny_root, cell):
         log, sched, _ = runner(config, traffic, seed, 2.0)
         del sched
         assert check(log, config, traffic, seed)["correct"]
-        res = check(log, config, traffic, seed, control=one_round())
+        res = check(log, config, traffic, seed,
+                    control=getattr(ctl, control)())
         assert not res["correct"], res
         assert res["score_regret"][0] >= 1
