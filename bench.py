@@ -439,7 +439,12 @@ class Workload:
             pk.intern_pod(p)
         self.pk = pk
         nt = pk.pack_nodes(nodes, existing)
-        self.dn = nodes_to_device(nt)
+        # one set of owner columns for every batch: the whole pending
+        # set's, so any chunk pairs with self.dn
+        pt_all = pk.pack_pods(pending)
+        self.owner_cols = pt_all.owner_cols
+        self.dn = nodes_to_device(nt, owner_counts=pk.pack_owner_columns(
+            nodes, existing, pt_all))
         self.ds = selectors_to_device(pk.pack_selector_tables())
         tt = pk.pack_topology_tables()
         self.dt = topology_to_device(tt) if tt.n_pairs else None
@@ -448,7 +453,7 @@ class Workload:
         from kubernetes_tpu.ops.priorities import solver_gates
 
         (self.skip_prio, self.no_ports, self.no_pod_affinity,
-         self.no_spread) = solver_gates(nt, pk.pack_pods(pending))
+         self.no_spread) = solver_gates(nt, pt_all)
         self.has_vol = bool(pvcs or pvs) or any(p.volumes for p in pending)
         self._volumes_to_device = volumes_to_device
         self._pods_to_device = pods_to_device
@@ -469,7 +474,9 @@ class Workload:
         hit = self._dev_batch_memo.get(key)
         if hit is not None:
             return hit
-        dp = self._pods_to_device(self.pk.pack_pods(chunk), pad_to=bucket_size(pad))
+        dp = self._pods_to_device(
+            self.pk.pack_pods(chunk, owner_cols=self.owner_cols),
+            pad_to=bucket_size(pad))
         dv = (
             self._volumes_to_device(self.pk.pack_volume_tables(chunk))
             if self.has_vol

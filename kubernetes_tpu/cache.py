@@ -30,8 +30,13 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from kubernetes_tpu.api.types import Node, Pod
+from kubernetes_tpu.obs.trace import annotate
 from kubernetes_tpu.sanitize import assert_held, make_lock
-from kubernetes_tpu.snapshot import NodeTable, SnapshotPacker
+from kubernetes_tpu.snapshot import (
+    NodeTable,
+    SnapshotPacker,
+    owner_anchor_pairs,
+)
 
 #: cache.go — factory.NewConfigFactory wires a 30 s assumed-pod TTL.
 DEFAULT_ASSUME_TTL_S = 30.0
@@ -69,6 +74,19 @@ class SchedulerCache:
         self._pod_deadline: Dict[str, float] = {}  # key -> expiry (EXPIRING only)
         self._dirty: Set[str] = set()  # node names needing row repack
         self._shape_dirty = True  # node set / widths changed => full repack
+        # ---- SelectorSpread owner counts (kept on pod insert/drop) -------
+        #: owner-set id -> {node name: cached pods there matching the set};
+        #: nonzero entries only. The solve reads the columns of its
+        #: batch's owner sets (owner_columns), so a new owner set costs
+        #: one column, never a repack of the cached pods
+        self._owner_counts: Dict[int, Dict[str, int]] = {}
+        #: owner sets below this id have their counts held; a set interned
+        #: later is counted on first need (_sync_owner_sets)
+        self._owner_synced = 0
+        #: (namespace, key, value) -> {pod key: node name} of the cached
+        #: pods carrying that label — where a new owner set's counts come
+        #: from (its selectors' anchor pair, snapshot.owner_anchor_pairs)
+        self._label_pods: Dict[Tuple[str, str, str], Dict[str, str]] = {}
         # cached snapshot state
         self._table: Optional[NodeTable] = None
         self._row_of: Dict[str, int] = {}
@@ -215,7 +233,7 @@ class SchedulerCache:
             raise CacheError(f"pod {key} already in cache ({self._pod_state[key]})")
         self.packer.intern_pod(pod)
         p = dataclasses.replace(pod, node_name=node_name)
-        self._pods_by_node.setdefault(node_name, {})[key] = p
+        self._store(p, node_name)
         self._pod_state[key] = _ASSUMED
         self._pod_node[key] = node_name
         self._mark_dirty(node_name)
@@ -276,7 +294,7 @@ class SchedulerCache:
                 self._pod_state[key] = _ADDED
                 self._pod_deadline.pop(key, None)
                 # refresh the stored object to the API's version
-                self._pods_by_node[pod.node_name][key] = pod
+                self._restore(pod, pod.node_name)
                 self._mark_dirty(pod.node_name)
         elif state is None:
             self._insert_pod(pod)
@@ -295,7 +313,7 @@ class SchedulerCache:
             self._insert_pod(pod)
             return
         self.packer.intern_pod(pod)
-        self._pods_by_node[old_node][key] = pod
+        self._restore(pod, old_node)
         self._mark_dirty(old_node)
 
     def remove_pod(self, pod_key: str) -> None:
@@ -329,7 +347,7 @@ class SchedulerCache:
         if not pod.node_name:
             raise CacheError(f"pod {pod.key()} has no node assignment")
         self.packer.intern_pod(pod)
-        self._pods_by_node.setdefault(pod.node_name, {})[pod.key()] = pod
+        self._store(pod, pod.node_name)
         self._pod_state[pod.key()] = _ADDED
         self._pod_node[pod.key()] = pod.node_name
         self._mark_dirty(pod.node_name)
@@ -338,10 +356,128 @@ class SchedulerCache:
         node = self._pod_node.pop(key)
         self._pod_state.pop(key, None)
         self._pod_deadline.pop(key, None)
-        pods = self._pods_by_node.get(node)
-        if pods:
-            pods.pop(key, None)
+        self._unstore(key, node)
         self._mark_dirty(node)
+
+    # -- stored pods and their owner counts ---------------------------------
+
+    # The stored pods, their label index and the owner counts change
+    # together under the snapshot lock: the extender's handler threads
+    # read them through owner_columns (and count a newly interned owner
+    # set there) while the driver stores and drops pods.
+
+    def _store(self, pod: Pod, node: str) -> None:
+        """Put ``pod`` on ``node`` (no object stored under its key there)."""
+        with self._snap_lock:
+            self._owner_upkeep(pod, node, 1)
+            self._pods_by_node.setdefault(node, {})[pod.key()] = pod
+
+    def _unstore(self, key: str, node: str) -> None:
+        with self._snap_lock:
+            pods = self._pods_by_node.get(node)
+            p = pods.get(key) if pods else None
+            if p is not None:
+                self._owner_upkeep(p, node, -1)
+                del pods[key]
+
+    def _restore(self, pod: Pod, node: str) -> None:
+        """Replace the object stored under ``pod``'s key on ``node``;
+        counts move only when the labels do."""
+        with self._snap_lock:
+            old = self._pods_by_node.get(node, {}).get(pod.key())
+            if (old is not None and old.labels == pod.labels
+                    and old.namespace == pod.namespace):
+                self._pods_by_node[node][pod.key()] = pod
+                return
+            self._unstore(pod.key(), node)
+            self._store(pod, node)
+
+    def _owner_upkeep(self, pod: Pod, node: str, sign: int) -> None:
+        """Count ``pod`` into (sign 1) or out of (-1) the per-node counts
+        of every owner set it matches, and file it under its labels.
+        Runs BEFORE the pod enters or leaves ``_pods_by_node``, so the
+        catch-up of a newly interned owner set (which reads the stored
+        pods) never sees it twice."""
+        assert_held(self._snap_lock, "cache._owner_upkeep")
+        u = self.packer.u
+        if not pod.labels and not u.owner_open:
+            return  # matches no owner set, files under no label
+        with annotate("cache.owner_counts"):
+            self._sync_owner_sets()
+            ns, key = pod.namespace, pod.key()
+            for k, v in pod.labels.items():
+                lk = (ns, k, v)
+                if sign > 0:
+                    self._label_pods.setdefault(lk, {})[key] = node
+                else:
+                    d = self._label_pods.get(lk)
+                    if d is not None:
+                        d.pop(key, None)
+                        if not d:
+                            del self._label_pods[lk]
+            synced = self._owner_synced
+            for o in u.matching_owner_sets(pod):
+                if o >= synced:
+                    # interned by another thread since the catch-up
+                    # above: the next catch-up counts it whole
+                    continue
+                c = self._owner_counts.setdefault(o, {})
+                n = c.get(node, 0) + sign
+                if n:
+                    c[node] = n
+                else:
+                    c.pop(node, None)
+
+    def _sync_owner_sets(self) -> None:
+        """Count every owner set interned since the last call from the
+        stored pods carrying its anchor label (all stored pods for a set
+        with no positive requirement)."""
+        assert_held(self._snap_lock, "cache._sync_owner_sets")
+        u = self.packer.u
+        n = len(u.owner_set_items)
+        for o in range(self._owner_synced, n):
+            ns, sels = u.owner_set_items[o]
+            pairs = owner_anchor_pairs(sels)
+            if pairs is None:
+                cands = [(p, node) for node, pods in self._pods_by_node.items()
+                         for p in pods.values()]
+            else:
+                cands = [(self._pods_by_node[node][key], node)
+                         for k, v in pairs
+                         for key, node in self._label_pods.get((ns, k, v),
+                                                               {}).items()]
+            counts: Dict[str, int] = {}
+            for p, node in cands:
+                if u.owner_set_matches(o, p.namespace, p.labels):
+                    counts[node] = counts.get(node, 0) + 1
+            if counts:
+                self._owner_counts[o] = counts
+        self._owner_synced = n
+
+    def owner_counts(self) -> Dict[int, Dict[str, int]]:
+        """owner-set id -> {node name: matching cached pods}, every owner
+        set interned so far (nonzero entries only; a copy)."""
+        with self._snap_lock:
+            self._sync_owner_sets()
+            return {o: dict(c) for o, c in self._owner_counts.items() if c}
+
+    def owner_columns(self, cols, width: int, n_rows: int) -> np.ndarray:
+        """(n_rows, width) f32 per-node counts of the owner sets ``cols``
+        (column c = set ``cols[c]``), rows in the last snapshot's order:
+        the SelectorSpread state a batch's solve reads. Costs O(n_rows x
+        width) plus the nonzero counts of those sets — never the cached
+        pods, nor the owner sets no column names."""
+        out = np.zeros((n_rows, width), np.float32)
+        if len(cols):
+            with self._snap_lock:
+                self._sync_owner_sets()
+                row_of = self._row_of
+                for c, o in enumerate(cols):
+                    for node, k in self._owner_counts.get(int(o), {}).items():
+                        r = row_of.get(node)
+                        if r is not None:
+                            out[r, c] = k
+        return out
 
     def _mark_dirty(self, node_name: str) -> None:
         if node_name in self._nodes:

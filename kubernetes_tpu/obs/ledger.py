@@ -93,6 +93,9 @@ def phase_of(span_name: str) -> str:
         return span_name.split(":", 1)[1].split("@", 1)[0]
     if span_name.startswith("solve:"):
         return "solve"
+    if span_name.startswith("snapshot:"):
+        # snapshot:owner_columns: a step of the snapshot phase
+        return "snapshot"
     if span_name.startswith(("extender", "grpc")):
         return "extenders"
     if span_name.startswith("scenario"):

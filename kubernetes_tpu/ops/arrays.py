@@ -42,7 +42,10 @@ class DeviceNodes(NamedTuple):
     port_wild_mh: jnp.ndarray  # (N, Upp) f32
     port_spec_mh: jnp.ndarray  # (N, Upip) f32
     image_mh: jnp.ndarray  # (N, Ui) f32
-    owner_counts: jnp.ndarray  # (N, Uo) f32
+    #: (N, Ob) f32 — per-node counts of the solve's pod batch's owner
+    #: columns (PodTable.owner_cols), set per batch by
+    #: attach_owner_columns; (N, 0) on a table no batch was attached to
+    owner_counts: jnp.ndarray
     zone_id: jnp.ndarray  # (N,) i32
     zone_valid: jnp.ndarray  # (Z,) bool — static shape = padded zone count
     avoid_mh: jnp.ndarray  # (N, Uu) f32
@@ -83,9 +86,9 @@ class DevicePods(NamedTuple):
     port_spec_pp: jnp.ndarray  # (P, Upp) f32
     port_spec_pip: jnp.ndarray  # (P, Upip) f32
     image_mh: jnp.ndarray  # (P, Ui) f32
-    owner_id: jnp.ndarray  # (P,) i32
+    owner_id: jnp.ndarray  # (P,) i32 — column of owner_counts, -1 none
     owner_uid_id: jnp.ndarray  # (P,) i32
-    owner_match_mh: jnp.ndarray  # (P, Uo) f32
+    owner_match_mh: jnp.ndarray  # (P, Ob) f32
     order: jnp.ndarray  # (P,) i32
     matcher_mh: jnp.ndarray  # (P, M) f32
     affprog_id: jnp.ndarray  # (P,) i32
@@ -211,11 +214,19 @@ def _pad_rows(a: np.ndarray, rows: int, fill=0) -> np.ndarray:
     return out
 
 
-def nodes_to_device(t: NodeTable, pad_to: int | None = None) -> DeviceNodes:
+def nodes_to_device(t: NodeTable, pad_to: int | None = None,
+                    owner_counts: np.ndarray | None = None) -> DeviceNodes:
+    """Upload a host NodeTable. ``owner_counts`` (t.n, Ob) are the owner
+    columns of the pod batch this table will be solved with
+    (SnapshotPacker.pack_owner_columns / SchedulerCache.owner_columns);
+    without them the table carries none, and a batch that spreads by an
+    owner must be attached with :func:`attach_owner_columns`."""
     n_pad = pad_to or bucket_size(max(t.n, 1))
     valid = np.zeros((n_pad,), bool)
     valid[: t.n] = True
     f32 = lambda a: jnp.asarray(_pad_rows(a.astype(np.float32), n_pad))
+    if owner_counts is None:
+        owner_counts = np.zeros((t.n, 0), np.float32)
     return DeviceNodes(
         valid=jnp.asarray(valid),
         name_id=jnp.asarray(_pad_rows(t.name_id, n_pad, -1)),
@@ -232,7 +243,7 @@ def nodes_to_device(t: NodeTable, pad_to: int | None = None) -> DeviceNodes:
         port_wild_mh=f32(t.port_wild_mh),
         port_spec_mh=f32(t.port_spec_mh),
         image_mh=f32(t.image_mh),
-        owner_counts=f32(t.owner_counts),
+        owner_counts=f32(owner_counts),
         zone_id=jnp.asarray(_pad_rows(t.zone_id, n_pad, -1)),
         zone_valid=jnp.asarray(t.zone_valid),
         avoid_mh=f32(t.avoid_mh),
@@ -298,6 +309,20 @@ def pods_to_device(t: PodTable, pad_to: int | None = None) -> DevicePods:
         vol_error=jnp.asarray(_pad_rows(t.vol_error, p_pad, False)),
         limits=f32(t.limits),
     )
+
+
+def attach_owner_columns(nodes: DeviceNodes,
+                         counts: np.ndarray) -> DeviceNodes:
+    """``nodes`` with its owner columns replaced by host ``counts``
+    (rows <= N, Ob): padded to the node axis and placed like the
+    table's other (N, ·) arrays (sharded along the mesh's node axis
+    when the table is). The resident table itself is untouched."""
+    n = nodes.requested.shape[0]
+    a = _pad_rows(counts.astype(np.float32, copy=False), n)
+    sharding = getattr(nodes.requested, "sharding", None)
+    return nodes._replace(owner_counts=(jax.device_put(a, sharding)
+                                        if sharding is not None
+                                        else jnp.asarray(a)))
 
 
 #: DeviceNodes fields that are NOT (N,)-row-shaped and therefore must not

@@ -75,7 +75,7 @@ class UsageState(NamedTuple):
     port_any: jnp.ndarray  # (N, Upp)
     port_wild: jnp.ndarray  # (N, Upp)
     port_spec: jnp.ndarray  # (N, Upip)
-    owner_counts: jnp.ndarray  # (N, Uo)
+    owner_counts: jnp.ndarray  # (N, Ob) — the batch's owner columns
     matcher_counts: jnp.ndarray  # (N, M)
     anti_counts: jnp.ndarray  # (N, Ua)
     sym_counts: jnp.ndarray  # (N, Us)

@@ -147,11 +147,23 @@ def selector_spread(pods, nodes, sel, topo, mask) -> jnp.ndarray:
     """selector_spreading.go: map = count of same-namespace pods on the node
     matching all owner selectors; reduce = 10*(max-count)/max blended 1/3
     with the zone-level equivalent at 2/3 (zoneWeighting, :34) when zones
-    exist."""
-    idx = jnp.clip(pods.owner_id, 0, nodes.owner_counts.shape[1] - 1)
-    counts = jnp.where(
-        (pods.owner_id >= 0)[:, None], nodes.owner_counts.T[idx], 0.0
-    )  # (P, N)
+    exist.
+
+    ``pods.owner_id`` indexes the batch's owner columns: ``nodes``
+    must carry the same batch's counts (arrays.attach_owner_columns)."""
+    width = nodes.owner_counts.shape[1]
+    if width != pods.owner_match_mh.shape[1]:
+        raise ValueError(
+            f"selector_spread: the node table carries {width} owner "
+            f"columns, the pod batch {pods.owner_match_mh.shape[1]}: "
+            "attach the batch's owner columns (attach_owner_columns)")
+    if width == 0:
+        counts = jnp.zeros(mask.shape, jnp.float32)
+    else:
+        idx = jnp.clip(pods.owner_id, 0, width - 1)
+        counts = jnp.where(
+            (pods.owner_id >= 0)[:, None], nodes.owner_counts.T[idx], 0.0
+        )  # (P, N)
     counts = jnp.where(mask, counts, 0.0)
     max_node = jnp.max(counts, axis=1, keepdims=True)  # (P, 1)
 

@@ -43,6 +43,7 @@ from kubernetes_tpu.api.types import Pod
 from kubernetes_tpu.cache import SchedulerCache
 from kubernetes_tpu.obs.trace import annotate
 from kubernetes_tpu.ops.arrays import (
+    attach_owner_columns,
     nodes_to_device,
     pods_to_device,
     selectors_to_device,
@@ -51,6 +52,7 @@ from kubernetes_tpu.ops.arrays import (
 from kubernetes_tpu.ops.predicates import run_predicates
 from kubernetes_tpu.ops.priorities import solver_gates
 from kubernetes_tpu.queue import SchedulingQueue
+from kubernetes_tpu.snapshot import owner_width
 from kubernetes_tpu.utils import klog
 from kubernetes_tpu.utils.interner import Interner, bucket_size
 
@@ -137,6 +139,9 @@ class CycleResult:
     #: (device_resident_snapshot off), "" = the cycle ended before the
     #: snapshot (empty queue / all-prefilter batches)
     snapshot_mode: str = ""
+    #: distinct owner sets the cycle's pods spread by (SelectorSpread):
+    #: the owner columns its solve read, before padding
+    owner_columns: int = 0
     #: which solve the cycle ran: "restricted" = the incremental
     #: candidate-column solve over the cached score plane (O(churn));
     #: "full" = the cold dense solve; "" = the cycle ended before any
@@ -1240,6 +1245,18 @@ class Scheduler:
                         self.recovery.device_cooloff_s)
                     return self.cache.snapshot(), None, "host"
 
+    def _with_owner_columns(self, dn, pt):
+        """``dn`` carrying the owner columns of pod batch ``pt``: per-node
+        counts of the owner sets its pods spread by, from the cache's
+        incrementally kept counts (SchedulerCache.owner_columns)."""
+        width = int(pt.owner_match_mh.shape[1])
+        if width == int(dn.owner_counts.shape[1]) and not len(pt.owner_cols):
+            return dn
+        with self.obs.span("snapshot:owner_columns"):
+            cols = self.cache.owner_columns(pt.owner_cols, width,
+                                            int(dn.valid.shape[0]))
+            return attach_owner_columns(dn, cols)
+
     def _place(self, t):
         """Replicate a device pytree across the node-axis mesh —
         identity when the sharded backend is off OR this cycle fell
@@ -1445,6 +1462,9 @@ class Scheduler:
                     res.attempted = 0
                     preflight_shed = True
                     use_pipeline = False
+            res.owner_columns = len(pt.owner_cols)
+            if not preflight_shed:
+                dn = self._with_owner_columns(dn, pt)
             dp = (None if use_pipeline or preflight_shed else self._place(
                   pods_to_device(pt, pad_to=(
                       pad_p or bucket_size(max(len(batch), 1))))))
@@ -1510,7 +1530,7 @@ class Scheduler:
             # nominated pods — _pipeline_eligible)
             return self._pipelined_tail(
                 batch, cycle, res, t0, trace, nt, dn, ds, dt, node_order,
-                skip_prio, no_ports, no_pod_aff, no_spread,
+                skip_prio, no_ports, no_pod_aff, no_spread, pt.owner_cols,
             )
 
         # incremental solve: a steady-state micro-batch on a clean/delta
@@ -1642,7 +1662,8 @@ class Scheduler:
         if nominated:
             row_of = {name: i for i, name in enumerate(node_order)}
             nom_pods = [p for p, _ in nominated]
-            dpn = self._place(pods_to_device(pk.pack_pods(nom_pods)))
+            dpn = self._place(pods_to_device(pk.pack_pods(
+                nom_pods, owner_cols=pt.owner_cols)))
             nom_rows = np.zeros((dpn.valid.shape[0],), np.int32)
             nom_ok = np.zeros((dpn.valid.shape[0],), bool)
             for j, (_, node) in enumerate(nominated):
@@ -3148,7 +3169,7 @@ class Scheduler:
 
     def _pipelined_tail(self, batch, cycle, res, t0, trace, nt, dn, ds, dt,
                         node_order, skip_prio, no_ports, no_pod_aff,
-                        no_spread) -> CycleResult:
+                        no_spread, owner_cols) -> CycleResult:
         """Double-buffered pack→solve→readback→bind pipeline over fixed
         sub-batches (SURVEY §7.2 step 9): while chunk k's solve runs on
         device (JAX async dispatch), the host packs chunk k+1 and applies
@@ -3156,7 +3177,9 @@ class Scheduler:
         are identical at every depth ≥ 2 — only host scheduling overlaps —
         so placements are depth-invariant by construction (pinned by
         tests/test_pipeline.py). Every chunk pads to ONE bucket, so the
-        whole cycle runs a single solver jit signature."""
+        whole cycle runs a single solver jit signature. Every chunk packs
+        against ``owner_cols``, the whole batch's owner columns (the ones
+        ``dn`` carries), so a chunk's placements count for the next."""
         import numpy as np
 
         from kubernetes_tpu.ops.assign import (
@@ -3200,8 +3223,9 @@ class Scheduler:
 
         def pack_chunk(k):
             with self.obs.span(f"pipeline:pack@{k}", pods=len(chunks[k])):
-                dp_c = self._place(pods_to_device(pk.pack_pods(chunks[k]),
-                                                  pad_to=chunk_pad))
+                dp_c = self._place(pods_to_device(
+                    pk.pack_pods(chunks[k], owner_cols=owner_cols),
+                    pad_to=chunk_pad))
                 dv_c = sv_c = None
                 if any(p.volumes for p in chunks[k]):
                     dv_c = self._place(
@@ -3352,7 +3376,8 @@ class Scheduler:
             if rows_dev is not None:
                 rows = self.obs.jax.readback("preempt-reasons", rows_dev)
             n_valid = nt.n
-            pt_c = pk.pack_pods(chunks[k])  # host rows (pack memo hit)
+            # host rows (pack memo hit)
+            pt_c = pk.pack_pods(chunks[k], owner_cols=owner_cols)
             res_names = (list(FIXED_RESOURCE_NAMES)
                          + pk.u.scalar_resources.items())[: pt_c.req.shape[1]]
             for j, i in enumerate(failed_idx):
@@ -4299,6 +4324,7 @@ class Scheduler:
                 dn = place_node_table(nt, self.mesh)
             else:
                 dn = nodes_to_device(nt)
+        dn = self._with_owner_columns(dn, pt)
         dp = self._place(pods_to_device(
             pt, pad_to=self._cascade_pad(len(pods_list))))
         ds = self._place(selectors_to_device(pk.pack_selector_tables()))
@@ -4613,6 +4639,16 @@ class Scheduler:
                        "(nodes bucket %d)", compiled, dn.valid.shape[0])
         return compiled
 
+    @staticmethod
+    def _warm_owner_pad(P, pk, sample) -> int:
+        """The owner-column width a real batch of bucket ``P`` will
+        present: that of the sample's first P pods, where a sample
+        shorter than P whose every pod spreads by an owner set of its
+        own (density.go's latency pods, each its own
+        ReplicationController) stands for P pods bringing P sets."""
+        n = len(pk.owner_cols_of(sample[:P]))
+        return owner_width(P if 0 < n == len(sample) < P else n)
+
     def _warm_bucket(self, P, pk, sample, nt, dn, ds, dt, solver, statics,
                      gates, has_vol_sample, wu, anchor: bool = False) -> int:
         """Compile one bucketed solve shape (the body of the warmup
@@ -4634,7 +4670,10 @@ class Scheduler:
         )
 
         skip_prio, no_ports, no_pod_aff, no_spread = gates
-        dp = self._place(pods_to_device(pk.pack_pods(sample[:P]), pad_to=P))
+        pt = pk.pack_pods(sample[:P], owner_pad=self._warm_owner_pad(
+            P, pk, sample))
+        dn = self._with_owner_columns(dn, pt)
+        dp = self._place(pods_to_device(pt, pad_to=P))
         dv = sv = None
         if has_vol_sample:
             # a volume-bearing sample warms the volume-bearing solve
@@ -4978,16 +5017,24 @@ class Scheduler:
                 # compile here too — but only the cold variant;
                 # partitioned never warm-starts or feeds extra_score
                 if P not in dps:
-                    dps[P] = self._place(pods_to_device(
-                        pk.pack_pods(sample[:P]), pad_to=P))
-                dp = dps[P]
+                    pt_w = pk.pack_pods(sample[:P], owner_pad=(
+                        self._warm_owner_pad(P, pk, sample)))
+                    dps[P] = (self._place(pods_to_device(pt_w, pad_to=P)),
+                              pt_w)
+                dp, pt_w = dps[P]
+                sub_w = sub_dn
+                if pt_w.owner_match_mh.shape[1]:
+                    # the frame carries the batch's owner columns,
+                    # gathered at the candidates like every other row
+                    sub_w = gather_node_rows(
+                        self._with_owner_columns(dn, pt_w), cand)
                 extra = None
                 if pack is not None and not over_limit:
                     # the pack's cost on the gathered frame — same
                     # jitted kernel, dtype and sharding as real
                     # restricted cycles feed extra_score with
                     extra = pack.cost(sample[:P], nt, node_order, dp,
-                                      sub_dn)
+                                      sub_w)
                 solve_kwargs = dict(
                     max_rounds=self.max_rounds,
                     per_node_cap=self.per_node_cap,
@@ -5015,7 +5062,7 @@ class Scheduler:
                                              extra_score=extra))
                 for var in variants:
                     self.obs.jax.record_call(
-                        "solve", dp, sub_dn, ds,
+                        "solve", dp, sub_w, ds,
                         static=("restricted", self.solver,
                                 tuple(skip_prio), self.pred_mask,
                                 self.per_node_cap, self.max_rounds,
@@ -5023,13 +5070,13 @@ class Scheduler:
                                 var["extra_score"] is None,
                                 self._mesh_live),
                         warmup=True)
-                    out = batch_assign(dp, sub_dn, ds, self.weights,
+                    out = batch_assign(dp, sub_w, ds, self.weights,
                                        **solve_kwargs, **var)
                     a, wu_usage = out[0], out[1]
                     if (self.robustness.validate_results
                             and not self.robustness.host_validate):
                         dv_out = device_validate(a, wu_usage, dp,
-                                                 sub_dn, self.pred_mask)
+                                                 sub_w, self.pred_mask)
                         if dv_out is not None:
                             jax.block_until_ready(dv_out[0])
                     jax.block_until_ready(
@@ -5185,7 +5232,12 @@ class Scheduler:
             # slope here means something interns per-pod-unique tokens
             "interned_items": interned,
             "universe_matcher_memo": len(u._matcher_row_memo),
-            "universe_owner_sets_memo": len(u._owner_sets_memo),
+            "universe_owner_index": len(u.owner_index),
+            # pod-keyed owner upkeep: filed labels and nonzero counts
+            # must RETURN to baseline when the pods leave
+            "cache_label_index": len(self.cache._label_pods),
+            "cache_owner_counts": sum(
+                len(c) for c in self.cache._owner_counts.values()),
             # device-side state — the drop edges (drop_device_snapshot,
             # _drop_incremental, host-mode demotion) must zero these;
             # a resident surviving its drop is device-memory leaked

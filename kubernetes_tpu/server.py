@@ -114,6 +114,7 @@ class ExtenderServer:
 
     def _evaluate(self, payload: dict):
         from kubernetes_tpu.ops.arrays import (
+            attach_owner_columns,
             nodes_to_device,
             pods_to_device,
             selectors_to_device,
@@ -128,8 +129,11 @@ class ExtenderServer:
         pk.intern_pod(pod)
         nt = s.cache.snapshot()
         node_order = s.cache.node_order()
+        pt = pk.pack_pods([pod])
         dn = nodes_to_device(nt)
-        dp = pods_to_device(pk.pack_pods([pod]))
+        dn = attach_owner_columns(dn, s.cache.owner_columns(
+            pt.owner_cols, pt.owner_match_mh.shape[1], dn.valid.shape[0]))
+        dp = pods_to_device(pt)
         ds = selectors_to_device(pk.pack_selector_tables())
         fr = run_predicates(dp, dn, ds, None, None, None, s.pred_mask)
         score = run_priorities(dp, dn, ds, fr.mask, s.weights)

@@ -96,6 +96,21 @@ def _canon_selector(sel: LabelSelector):
     )
 
 
+def owner_anchor_pairs(selectors) -> Optional[List[Tuple[str, str]]]:
+    """The (key, value) pairs one of which every pod matching ALL of
+    ``selectors`` must carry: the first matchLabels pair of the first
+    selector that has one, else every value of its first In
+    requirement. None when no selector has a positive requirement."""
+    for s in selectors:
+        if s.match_labels:
+            return [min(s.match_labels.items())]
+    for s in selectors:
+        for r in s.match_expressions:
+            if r.operator == OP_IN:
+                return [(r.key, v) for v in dict.fromkeys(r.values)]
+    return None
+
+
 @dataclass(frozen=True)
 class CompiledExpr:
     op: int
@@ -132,9 +147,20 @@ class Universe:
         # owner-selector sets (SelectorSpread) — (namespace, canonical sels)
         self.owner_sets = Interner()
         self.owner_set_items: List[Tuple[str, tuple]] = []
+        #: (namespace, key, value) -> owner sets anchored on that pair:
+        #: each set is filed under ONE positive requirement of its
+        #: selectors (a matchLabels pair, else every value of an In), so
+        #: matching a pod costs O(its labels), not O(owner sets)
+        self.owner_index: Dict[Tuple[str, str, str], List[int]] = {}
+        #: namespace -> owner sets with no positive requirement (empty,
+        #: NotIn / Exists / DoesNotExist only): checked against every pod
+        self.owner_open: Dict[str, List[int]] = {}
         # zone keys (region, zone) — SelectorSpread zone weighting
         self.zones = Interner()
-        # controller owner UIDs — NodePreferAvoidPods
+        # controller owner UIDs that some node's preferAvoidPods lists —
+        # NodePreferAvoidPods. Interned from nodes only: a pod's uid that
+        # no node lists cannot be avoided anywhere (lookup -1), and node
+        # rows never change when a pod brings a new controller
         self.owner_uids = Interner()
         # ---- inter-pod affinity / topology spread universes --------------
         # (tensor form of predicates/metadata.go topologyPairsMaps :65)
@@ -167,15 +193,12 @@ class Universe:
         self.pd_volumes = Interner()  # (filter_idx, token) — MaxPDVolumeCount
         self.csi_drivers = Interner()  # CSI driver names
         self.csi_volumes = Interner()  # (driver_id, handle)
-        # ---- label-fingerprint memos (pack-time hot path) ----------------
+        # ---- label-fingerprint memo (pack-time hot path) -----------------
         # pods overwhelmingly share label sets (every pod of one RS /
-        # service carries identical labels), so matcher and owner-set
-        # evaluation memoizes on (ns, sorted labels); registry LENGTH in
-        # the key invalidates when new matchers/sets are interned. The
-        # TPU headline measured packing at 17% of wall — these two memos
-        # are most of the selector-evaluation half of that.
+        # service carries identical labels), so matcher evaluation
+        # memoizes on (ns, sorted labels); registry LENGTH in the key
+        # invalidates when new matchers are interned.
         self._matcher_row_memo: Dict[tuple, np.ndarray] = {}
-        self._owner_sets_memo: Dict[tuple, List[int]] = {}
 
     # -- resources ---------------------------------------------------------
 
@@ -521,8 +544,46 @@ class Universe:
         )
         oid = self.owner_sets.intern(key)
         if oid == len(self.owner_set_items):
+            # indexed BEFORE it is published in owner_set_items: the
+            # driver's owner counts (SchedulerCache) bound the sets by
+            # owner_set_items while an extender thread interns a request
+            # pod's set, and must find every set they see in the index
+            pairs = owner_anchor_pairs(selectors)
+            if pairs is None:
+                self.owner_open.setdefault(namespace, []).append(oid)
+            else:
+                for k, v in pairs:
+                    self.owner_index.setdefault(
+                        (namespace, k, v), []).append(oid)
             self.owner_set_items.append((namespace, tuple(selectors)))
         return oid
+
+    def owner_set_matches(self, oid: int, namespace: str,
+                          labels: Dict[str, str]) -> bool:
+        ns, sels = self.owner_set_items[oid]
+        return ns == namespace and all(s.matches(labels) for s in sels)
+
+    def matching_owner_sets(self, pod: Pod) -> List[int]:
+        """Owner-set ids whose (namespace, selectors) match this pod —
+        the single source of truth for SelectorSpread matching (the
+        cache's per-node owner counts and PodTable.owner_match_mh, which
+        the assignment usage updates assume are computed identically).
+        Candidates come from the anchor index under the pod's own label
+        pairs plus the namespace's unanchored sets, each checked in
+        full: O(labels + candidates), whatever the owner universe."""
+        ns, labels = pod.namespace, pod.labels
+        n = len(self.owner_set_items)  # sets indexed but not yet published
+        out: List[int] = []
+        idx = self.owner_index
+        if idx:
+            for k, v in labels.items():
+                for o in idx.get((ns, k, v), ()):
+                    if o < n and self.owner_set_matches(o, ns, labels):
+                        out.append(o)
+        for o in self.owner_open.get(ns, ()):
+            if o < n and self.owner_set_matches(o, ns, labels):
+                out.append(o)
+        return out
 
 
 # ---------------------------------------------------------------------------
@@ -551,7 +612,6 @@ class NodeTable:
     port_wild_mh: np.ndarray  # (N, Upp) i8 — used with wildcard hostIP
     port_spec_mh: np.ndarray  # (N, Upip) i8 — used with specific hostIP
     image_mh: np.ndarray  # (N, Ui) i8
-    owner_counts: np.ndarray  # (N, Uo) f32 — matching scheduled pods per owner set
     zone_id: np.ndarray  # (N,) i32 — interned (region, zone); -1 unlabeled
     zone_valid: np.ndarray  # (Z,) bool — static zone-universe size carrier
     avoid_mh: np.ndarray  # (N, Uu) i8 — preferAvoidPods owner UIDs
@@ -593,11 +653,17 @@ class PodTable:
     port_spec_pp: np.ndarray  # (P, Upp) i8 — specific-IP ports, (proto,port) view
     port_spec_pip: np.ndarray  # (P, Upip) i8
     image_mh: np.ndarray  # (P, Ui) i8
-    owner_id: np.ndarray  # (P,) i32, -1 = no owning service/controller
-    owner_uid_id: np.ndarray  # (P,) i32, -1 = no controller ownerRef
-    #: which owner sets this pod's labels match — placing the pod bumps
-    #: those columns of NodeTable.owner_counts (device-side spread update)
-    owner_match_mh: np.ndarray  # (P, Uo) i8
+    #: the batch's owner columns: column c is owner set owner_cols[c] —
+    #: every distinct owner set a pod of the batch spreads by, in order
+    #: of first appearance. The solve sees per-node counts of these
+    #: columns only (SchedulerCache.owner_columns), never the universe
+    owner_cols: np.ndarray  # (Ob,) i32 — global owner-set ids
+    owner_id: np.ndarray  # (P,) i32 column in owner_cols, -1 = no owner
+    owner_uid_id: np.ndarray  # (P,) i32, -1 = no node avoids its controller
+    #: which of the batch's owner columns this pod's labels match —
+    #: placing the pod bumps those columns of the solve's owner counts
+    #: (device-side spread update)
+    owner_match_mh: np.ndarray  # (P, owner_width(Ob)) i8
     order: np.ndarray  # (P,) i32 — original index of each row (sort tracking)
     # ---- inter-pod affinity / spread -------------------------------------
     matcher_mh: np.ndarray  # (P, M) i8 — matchers this pod satisfies
@@ -736,27 +802,11 @@ def _pod_has_affinity(pod: Pod) -> bool:
     )
 
 
-def _matching_owner_sets(u: Universe, pod: Pod) -> List[int]:
-    """Owner-set ids whose (namespace, selectors) match this pod — the
-    single source of truth for SelectorSpread matching, used for both
-    NodeTable.owner_counts and PodTable.owner_match_mh (which the
-    assignment usage updates assume are computed identically).
-    Memoized per (registry length, ns, labels) — see Universe's
-    fingerprint memos."""
-    n = len(u.owner_set_items)
-    key = (n, pod.namespace, tuple(sorted(pod.labels.items())))
-    hit = u._owner_sets_memo.get(key)
-    if hit is not None:
-        return hit
-    if u._owner_sets_memo and next(iter(u._owner_sets_memo))[0] != n:
-        u._owner_sets_memo.clear()  # registry grew: all entries stale
-    out = [
-        o
-        for o, (ns, sels) in enumerate(u.owner_set_items)
-        if ns == pod.namespace and all(s.matches(pod.labels) for s in sels)
-    ]
-    u._owner_sets_memo[key] = out
-    return out
+def owner_width(n_cols: int) -> int:
+    """Padded owner-column width of a batch with ``n_cols`` distinct
+    owner sets: 0 when none (the spread kernel compiles out), else the
+    power-of-two bucket."""
+    return bucket_size(n_cols) if n_cols else 0
 
 
 class SnapshotPacker:
@@ -926,14 +976,14 @@ class SnapshotPacker:
             iid = u.images.intern(img)
             if iid == len(u.image_sizes):
                 u.image_sizes.append(0.0)
-        if pod.owner_uid:
-            u.owner_uids.intern(pod.owner_uid)
         self._remember_pod(self._pod_refs, ck, refs)
         return refs
 
     def intern_node(self, node: Node) -> int:
         u = self.u
         nid = u.node_names.intern(node.name)
+        for uid in node.prefer_avoid_owner_uids:
+            u.owner_uids.intern(uid)
         for t in node.taints:
             u.intern_taint(t.key, t.value, t.effect)
         for img, size in node.images.items():
@@ -965,7 +1015,8 @@ class SnapshotPacker:
         the resource-universe width, and the pack epoch (volume-state /
         claim-lifecycle / forget invalidations). Two packs of the same
         pods under equal signatures are bit-identical."""
-        return (*self.universe_node_sig(), self._pack_epoch)
+        return (*self.universe_node_sig(), len(self.u.owner_sets),
+                self._pack_epoch)
 
     def universe_node_sig(self) -> Tuple:
         """Node-row content signature: every interner's length + the
@@ -977,11 +1028,14 @@ class SnapshotPacker:
         :meth:`universe_sig` this excludes the pack epoch: forget_pod
         and claim-lifecycle invalidations never change node rows
         (volume-STATE replacement does, and set_volume_state callers
-        invalidate the snapshot explicitly — scheduler.set_volume_state)."""
+        invalidate the snapshot explicitly — scheduler.set_volume_state).
+        Owner sets are left out: node rows hold no owner counts (the
+        cache keeps them per owner set, SchedulerCache.owner_columns), so
+        a pod bringing a new owner set changes only the rows it lands on."""
         u = self.u
         lens = tuple(
-            len(v) for _, v in sorted(vars(u).items())
-            if isinstance(v, Interner)
+            len(v) for k, v in sorted(vars(u).items())
+            if isinstance(v, Interner) and k != "owner_sets"
         )
         return (lens, len(u.image_sizes), u.n_resources())
 
@@ -1009,7 +1063,6 @@ class SnapshotPacker:
             "Upp": bucket_size(len(u.ports_pp)),
             "Upip": bucket_size(len(u.ports_pip)),
             "Ui": bucket_size(len(u.images)),
-            "Uo": bucket_size(len(u.owner_sets)),
             "Uu": bucket_size(len(u.owner_uids)),
             "K": bucket_size(len(u.topo_keys), 2),
             "Utp": bucket_size(len(u.topo_pairs)),
@@ -1069,7 +1122,6 @@ class SnapshotPacker:
         port_wild = np.zeros((n, w["Upp"]), np.int8)
         port_spec = np.zeros((n, w["Upip"]), np.int8)
         image_mh = np.zeros((n, w["Ui"]), np.int8)
-        owner_counts = np.zeros((n, w["Uo"]), np.float32)
         zone_id = np.full((n,), -1, np.int32)
         avoid_mh = np.zeros((n, w["Uu"]), np.int8)
         ready = np.zeros((n,), bool)
@@ -1163,7 +1215,6 @@ class SnapshotPacker:
         native.aggregate_usage(req_mat, nz_mat, pod_rows, requested, nonzero_req)
 
         has_matchers = bool(u.pod_matcher_items)
-        has_owners = bool(u.owner_set_items)
         for p, i in zip(scheduled_pods, pod_rows):
             if i < 0:
                 continue
@@ -1174,12 +1225,6 @@ class SnapshotPacker:
                     port_wild[i, ppi] = 1
                 else:
                     port_spec[i, u.ports_pip.intern((proto, ip, port))] = 1
-            # owner_counts: for SelectorSpread we need, per owner-set, how
-            # many *matching* scheduled pods sit on each node. A scheduled
-            # pod contributes to owner set `o` if it matches o's selectors.
-            if has_owners:
-                for o in _matching_owner_sets(u, p):
-                    owner_counts[i, o] += 1
             # inter-pod affinity / spread count matrices
             if has_matchers:
                 matcher_counts[i] += self.u.pod_matcher_row(p, w["M"])
@@ -1236,7 +1281,6 @@ class SnapshotPacker:
             port_wild_mh=port_wild,
             port_spec_mh=port_spec,
             image_mh=image_mh,
-            owner_counts=owner_counts,
             zone_id=zone_id,
             zone_valid=(
                 np.arange(bucket_size(max(len(u.zones), 1))) < len(u.zones)
@@ -1281,36 +1325,84 @@ class SnapshotPacker:
         (it compares ``widths()`` before/after)."""
         return self.pack_nodes(nodes, scheduled_pods)
 
+    # -- owner columns -----------------------------------------------------
+
+    def pack_owner_columns(self, nodes: Sequence[Node],
+                           scheduled_pods: Sequence[Pod],
+                           table: PodTable) -> np.ndarray:
+        """(len(nodes), width) per-node counts of ``table``'s owner
+        columns, recounted from ``scheduled_pods``: the pods on each node
+        matching each column's owner set (selector_spreading.go's map
+        step). The one-shot form for callers without a SchedulerCache
+        (which keeps the same counts incrementally); rows follow
+        ``nodes``, pods on other nodes count nowhere."""
+        out = np.zeros((len(nodes), table.owner_match_mh.shape[1]),
+                       np.float32)
+        col_of = {int(o): c for c, o in enumerate(table.owner_cols)}
+        if not col_of:
+            return out
+        row_of = {nd.name: i for i, nd in enumerate(nodes)}
+        for p in scheduled_pods:
+            i = row_of.get(p.node_name)
+            if i is None:
+                continue
+            self.intern_pod(p)
+            for o in self.u.matching_owner_sets(p):
+                c = col_of.get(o)
+                if c is not None:
+                    out[i, c] += 1
+        return out
+
     # -- pods --------------------------------------------------------------
 
-    def pack_pods(self, pods: Sequence[Pod]) -> PodTable:
+    def owner_cols_of(self, pods: Sequence[Pod]) -> Tuple[int, ...]:
+        """The distinct owner sets ``pods`` spread by, in order of first
+        appearance: a batch's owner columns (pack_pods' default)."""
+        refs = (self.intern_pod(p)[3] for p in pods)
+        return tuple(dict.fromkeys(o for o in refs if o >= 0))
+
+    def pack_pods(self, pods: Sequence[Pod],
+                  owner_cols: Optional[Sequence[int]] = None,
+                  owner_pad: Optional[int] = None) -> PodTable:
         """Columnar pending-pod batch, memoized per (batch identity,
-        universe signature): the steady-state driver re-packs the same
-        backoff-retried pods and the bench re-packs its warmed chunk —
-        under an unchanged signature the previous table is bit-identical
-        by construction, so the per-pod packing loop collapses to one
-        tuple hash. Any universe growth or pack-epoch bump (volume state,
-        claim lifecycle, forget_pod) changes the signature and misses."""
-        for p in pods:
-            self.intern_pod(p)
+        owner columns, universe signature): the steady-state driver
+        re-packs the same backoff-retried pods and the bench re-packs its
+        warmed chunk — under an unchanged signature the previous table is
+        bit-identical by construction, so the per-pod packing loop
+        collapses to one tuple hash. Any universe growth or pack-epoch
+        bump (volume state, claim lifecycle, forget_pod) changes the
+        signature and misses.
+
+        ``owner_cols`` (default: :meth:`owner_cols_of` the batch) fixes
+        the owner columns — a pipelined chunk or the nominated pods
+        packed against their cycle's columns; a pod whose own owner set
+        is not among them gets ``owner_id`` -1. ``owner_pad`` widens the
+        padded column count past :func:`owner_width` (warmup presents
+        the widths real batches will)."""
+        refs = [self.intern_pod(p) for p in pods]
+        cols = (tuple(int(o) for o in owner_cols) if owner_cols is not None
+                else tuple(dict.fromkeys(r[3] for r in refs if r[3] >= 0)))
+        width = max(owner_width(len(cols)), int(owner_pad or 0))
         ids = tuple((p.key(), p.uid) for p in pods)
-        key = (ids, self.universe_sig())
+        key = (ids, cols, width, self.universe_sig())
         hit = self._memo_get(self._pod_table_memo, key)
         if hit is not None:
             return hit
-        table = self._pack_pods_uncached(pods)
+        table = self._pack_pods_uncached(pods, cols, width)
         # packing the rows may itself have interned (ports seen first at
         # pack time) — store under the POST-pack signature so the next
         # identical call (whose intern loop is then a no-op) hits
-        self._memo_put(self._pod_table_memo, (ids, self.universe_sig()),
-                       table)
+        self._memo_put(self._pod_table_memo,
+                       (ids, cols, width, self.universe_sig()), table)
         return table
 
-    def _pack_pods_uncached(self, pods: Sequence[Pod]) -> PodTable:
+    def _pack_pods_uncached(self, pods: Sequence[Pod],
+                            cols: Tuple[int, ...], width: int) -> PodTable:
         # pods are already interned — pack_pods (the only caller) runs
         # the intern loop before computing the memo key
         u = self.u
         w = self.widths()
+        col_of = {o: c for c, o in enumerate(cols)}
         n = len(pods)
         R = w["R"]
         req = np.zeros((n, R), np.float32)
@@ -1326,7 +1418,7 @@ class SnapshotPacker:
         image_mh = np.zeros((n, w["Ui"]), np.int8)
         owner = np.full((n,), -1, np.int32)
         owner_uid = np.full((n,), -1, np.int32)
-        owner_match = np.zeros((n, w["Uo"]), np.int8)
+        owner_match = np.zeros((n, width), np.int8)
         matcher_mh = np.zeros((n, w["M"]), np.int8)
         affprog = np.full((n,), -1, np.int32)
         prefaffprog = np.full((n,), -1, np.int32)
@@ -1345,8 +1437,9 @@ class SnapshotPacker:
 
         for i, p in enumerate(pods):
             refs = self.intern_pod(p)
-            (selprog[i], prefprog[i], tolset[i], owner[i],
+            (selprog[i], prefprog[i], tolset[i], _,
              affprog[i], prefaffprog[i], spread_hard[i], spread_soft[i]) = refs
+            owner[i] = col_of.get(refs[3], -1)
             matcher_mh[i] = u.pod_matcher_row(p, w["M"])
             for a in u.pod_anti_term_ids(p):
                 anti_term_mh[i, a] += 1
@@ -1377,11 +1470,14 @@ class SnapshotPacker:
                 if ii >= 0:
                     image_mh[i, ii] = 1
             if p.owner_uid:
-                # lookup, not intern: widths are frozen for this pack; the
-                # uid was interned on arrival (intern_pod)
+                # lookup: only uids some node avoids are interned
+                # (intern_node); any other uid is avoided nowhere
                 owner_uid[i] = u.owner_uids.lookup(p.owner_uid)
-            for o in _matching_owner_sets(u, p):
-                owner_match[i, o] = 1
+            if col_of:
+                for o in u.matching_owner_sets(p):
+                    c = col_of.get(o)
+                    if c is not None:
+                        owner_match[i, c] = 1
             if p.volumes:
                 rv = self.resolve_volumes(p)
                 vol_error[i] = rv.error
@@ -1409,6 +1505,7 @@ class SnapshotPacker:
             port_spec_pp=port_spec_pp,
             port_spec_pip=port_spec_pip,
             image_mh=image_mh,
+            owner_cols=np.asarray(cols, np.int32),
             owner_id=owner,
             owner_uid_id=owner_uid,
             owner_match_mh=owner_match,

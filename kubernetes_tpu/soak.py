@@ -66,7 +66,7 @@ DEFAULT_TOLERANCE: Dict[str, float] = {
     "rss_kb": 65536,               # 64 MB of allocator/arena settling
     "sched.interned_items": 256,
     "sched.universe_matcher_memo": 256,
-    "sched.universe_owner_sets_memo": 256,
+    "sched.universe_owner_index": 256,
     "sched.packer_pod_table_memo": 1024,   # LRU-capped upstream
     "sched.packer_vol_table_memo": 1024,
     "sched.breakers": 8,           # lazily minted per target, bounded

@@ -22,7 +22,9 @@ def build(nodes, scheduled, pending):
     nt = pk.pack_nodes(nodes, scheduled)
     pt = pk.pack_pods(pending)
     st = pk.pack_selector_tables()
-    return nodes_to_device(nt), pods_to_device(pt), selectors_to_device(st)
+    oc = pk.pack_owner_columns(nodes, scheduled, pt)
+    return (nodes_to_device(nt, owner_counts=oc), pods_to_device(pt),
+            selectors_to_device(st))
 
 
 def check_valid_assignment(assigned, pending, nodes, scheduled):

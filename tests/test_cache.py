@@ -5,6 +5,7 @@
 import numpy as np
 import pytest
 
+from kubernetes_tpu.api.types import LabelSelector
 from kubernetes_tpu.cache import CacheError, SchedulerCache
 from kubernetes_tpu.snapshot import RES_CPU, SnapshotPacker
 from kubernetes_tpu.testing import make_node, make_pod
@@ -107,7 +108,7 @@ def test_add_pod_corrects_wrong_assumption():
 def _assert_tables_equal(a, b):
     for f in (
         "allocatable requested nonzero_req pair_mh taint_hard_mh port_any_mh "
-        "owner_counts matcher_counts anti_counts sym_counts aff_pod_count"
+        "matcher_counts anti_counts sym_counts aff_pod_count"
     ).split():
         np.testing.assert_array_equal(getattr(a, f), getattr(b, f), err_msg=f)
 
@@ -145,6 +146,12 @@ def test_incremental_snapshot_matches_full_repack():
     # row orders agree (same insertion order)
     assert c.node_order() == c2.node_order()
     _assert_tables_equal(inc, full)
+    # the SelectorSpread counts each cache keeps per owner set agree too
+    svc = LabelSelector(match_labels={"app": "a0"})
+    for cc in (c, c2):
+        cc.packer.intern_pod(make_pod("pending", labels={"app": "a0"},
+                                      spread_selectors=(svc,)))
+    assert c.owner_counts() == c2.owner_counts() != {}
 
 
 def test_incremental_snapshot_after_universe_growth_falls_back():

@@ -20,8 +20,11 @@ def build(n_nodes=64, n_existing=40, n_pending=96):
     pk = SnapshotPacker()
     for p in existing + pending:
         pk.intern_pod(p)
-    dn = nodes_to_device(pk.pack_nodes(nodes, existing))
-    dp = pods_to_device(pk.pack_pods(pending))
+    pt = pk.pack_pods(pending)
+    dn = nodes_to_device(pk.pack_nodes(nodes, existing),
+                         owner_counts=pk.pack_owner_columns(
+                             nodes, existing, pt))
+    dp = pods_to_device(pt)
     ds = selectors_to_device(pk.pack_selector_tables())
     return dp, dn, ds, pending
 

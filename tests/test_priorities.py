@@ -23,7 +23,9 @@ def build(nodes, scheduled, pending):
     nt = pk.pack_nodes(nodes, scheduled)
     pt = pk.pack_pods(pending)
     st = pk.pack_selector_tables()
-    dn, dp, ds = nodes_to_device(nt), pods_to_device(pt), selectors_to_device(st)
+    oc = pk.pack_owner_columns(nodes, scheduled, pt)
+    dn = nodes_to_device(nt, owner_counts=oc)
+    dp, ds = pods_to_device(pt), selectors_to_device(st)
     mask = run_predicates(dp, dn, ds).mask
     return dn, dp, ds, mask
 
