@@ -587,7 +587,8 @@ class ServingConfig:
     #: legacy loop in cli.run
     enabled: bool = False
     #: shortest accumulation after the first pending pod — the burst-
-    #: coalescing debounce (a bucket-fill may still flush at min_wait)
+    #: coalescing debounce (a bucket-fill may still flush at min_wait;
+    #: 8 or more pods flush once the depth has not grown for min_wait)
     min_wait_s: float = 0.005
     #: latency ceiling: the window always flushes by max_wait
     max_wait_s: float = 0.05

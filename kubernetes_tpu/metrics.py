@@ -731,7 +731,8 @@ class SchedulerMetrics:
             "scheduler_microbatch_flushes_total",
             "Micro-batch window flushes by trigger (bucket-fill = the "
             "accumulated depth hit a warmed power-of-two bucket; "
-            "max-wait = the latency ceiling expired).",
+            "quiet = a burst of at least 8 pods stopped growing for "
+            "min_wait; max-wait = the latency ceiling expired).",
             ["trigger"],
         ))
         self.microbatch_window = r.register(Histogram(

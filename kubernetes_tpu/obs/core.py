@@ -213,9 +213,9 @@ class Observability:
 
     def note_microbatch(self, trigger: str, window_s: float) -> None:
         """The serving loop's micro-batch provenance for this cycle:
-        what flushed the accumulation window (bucket-fill | max-wait)
-        and how long it held — so a latency incident in the flight
-        record separates window time from solve time."""
+        what flushed the accumulation window (bucket-fill | quiet |
+        max-wait) and how long it held — so a latency incident in the
+        flight record separates window time from solve time."""
         self._scratch["flush_trigger"] = trigger
         self._scratch["window_s"] = window_s
 

@@ -70,8 +70,8 @@ class CycleRecord:
     #: sub-batches the pipelined executor ran (0 = monolithic cycle)
     pipeline_chunks: int = 0
     #: what flushed the serving loop's micro-batch window into this
-    #: cycle ("bucket-fill" | "max-wait"; "" = not a serving cycle) and
-    #: how long the window accumulated before flushing
+    #: cycle ("bucket-fill" | "quiet" | "max-wait"; "" = not a serving
+    #: cycle) and how long the window accumulated before flushing
     flush_trigger: str = ""
     window_s: float = 0.0
     #: recovery provenance: this is the first cycle after a takeover /

@@ -170,7 +170,8 @@ class CycleResult:
     #: scheduler_e2e_scheduling_duration_seconds
     e2e_latency_s: Dict[str, float] = field(default_factory=dict)
     #: what flushed the micro-batch window into this cycle
-    #: ("bucket-fill" | "max-wait"; "" = not a serving-loop cycle)
+    #: ("bucket-fill" | "quiet" | "max-wait"; "" = not a serving-loop
+    #: cycle)
     flush_trigger: str = ""
     #: how long the micro-batch window accumulated before flushing
     window_s: float = 0.0

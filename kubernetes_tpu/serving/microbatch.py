@@ -15,6 +15,13 @@ for shape-perfect batches:
   or, once ``min_wait`` has elapsed, any exact power-of-two boundary
   (zero padding waste; waiting longer only adds latency until a 2x
   larger bucket could fill);
+- it flushes once the burst has ENDED (``"quiet"``): at least
+  ``MIN_BUCKET`` pods pending and the depth has not grown for
+  ``min_wait`` since it last grew. A burst that stopped short of a
+  boundary (40 pods pad to the 64 bucket now or later alike) gains
+  nothing by waiting; a burst still arriving keeps pushing its own
+  deadline back, so it is never split. Below ``MIN_BUCKET`` the window
+  keeps waiting: further arrivals ride the same 8-pod bucket for free;
 - it flushes unconditionally at ``max_wait`` — the latency ceiling a
   trickle workload pays.
 
@@ -49,7 +56,7 @@ class WindowDecision:
     """What the window wants done right now."""
 
     flush: bool = False
-    #: why ("bucket-fill" | "max-wait"); "" when not flushing
+    #: why ("bucket-fill" | "quiet" | "max-wait"); "" when not flushing
     trigger: str = ""
     #: when not flushing: how long the loop may wait before the next
     #: decision point (doorbell rings cut it short)
@@ -78,20 +85,25 @@ class MicroBatchWindow:
         self.target_bucket = tb if tb <= target_bucket else tb // 2
         #: None = closed; else the clock stamp of the first pending pod
         self.opened_at: Optional[float] = None
+        #: clock stamp of the last look at which the depth grew (the
+        #: opening counts), and the depth that look saw
+        self.grew_at: Optional[float] = None
+        self.last_depth = 0
 
     @property
     def open(self) -> bool:
         return self.opened_at is not None
 
     def reset(self) -> None:
-        self.opened_at = None
+        self.opened_at = self.grew_at = None
+        self.last_depth = 0
 
     def close(self, now: Optional[float] = None) -> float:
         """Close the window (the caller is about to flush); returns the
         accumulation duration actually spent."""
         now = self.clock() if now is None else now
         w = now - self.opened_at if self.opened_at is not None else 0.0
-        self.opened_at = None
+        self.reset()
         return max(w, 0.0)
 
     def observe(self, depth: int, now: Optional[float] = None) -> WindowDecision:
@@ -101,10 +113,13 @@ class MicroBatchWindow:
             # nothing pending: an open window with zero depth means the
             # pods left by another path (delete, competing binder) —
             # close it rather than flushing an empty cycle at max_wait
-            self.opened_at = None
+            self.reset()
             return WindowDecision()
         if self.opened_at is None:
             self.opened_at = now
+        if depth > self.last_depth:
+            self.grew_at = now
+        self.last_depth = depth
         if depth >= self.target_bucket:
             return WindowDecision(flush=True, trigger="bucket-fill")
         elapsed = now - self.opened_at
@@ -116,9 +131,17 @@ class MicroBatchWindow:
             # flushing now wastes zero padding, and any further
             # accumulation re-pays latency until a 2x bucket could fill
             return WindowDecision(flush=True, trigger="bucket-fill")
+        quiet_at = self.grew_at + self.min_wait_s
+        if depth >= MIN_BUCKET and now >= quiet_at:
+            # the burst has ended short of a boundary: it pads to the
+            # same bucket whenever it flushes, so waiting on only adds
+            # latency
+            return WindowDecision(flush=True, trigger="quiet")
         deadline = self.opened_at + self.max_wait_s
         if elapsed < self.min_wait_s:
             deadline = min(deadline, self.opened_at + self.min_wait_s)
+        if depth >= MIN_BUCKET:
+            deadline = min(deadline, quiet_at)
         return WindowDecision(wait_s=max(deadline - now, 0.0))
 
 

@@ -200,6 +200,67 @@ def test_window_rejects_inverted_waits():
         MicroBatchWindow(min_wait_s=0.1, max_wait_s=0.05)
 
 
+def test_window_flushes_quiet_when_burst_ends_off_boundary():
+    clk = FakeClock()
+    w = MicroBatchWindow(clock=clk, min_wait_s=0.005, max_wait_s=0.05,
+                         target_bucket=256)
+    d = w.observe(40)  # one whole burst; 40 is no power of two
+    assert not d.flush and d.wait_s == pytest.approx(0.005)
+    clk.advance(0.002)
+    d = w.observe(40)  # no growth: the wait runs to last growth + min_wait
+    assert not d.flush and clk.t + d.wait_s == pytest.approx(0.005)
+    clk.advance(d.wait_s)
+    d = w.observe(40)
+    assert d.flush and d.trigger == "quiet"
+    assert w.close() == pytest.approx(0.005)
+    assert not w.open and w.grew_at is None
+
+
+def test_window_quiet_waits_for_a_burst_arriving_in_parts():
+    clk = FakeClock()
+    w = MicroBatchWindow(clock=clk, min_wait_s=0.005, max_wait_s=0.05,
+                         target_bucket=256)
+    assert not w.observe(24).flush
+    clk.advance(0.003)
+    d = w.observe(40)  # the burst's second part
+    assert not d.flush
+    clk.advance(0.002)  # the first part's quiet deadline has passed...
+    d = w.observe(40)
+    # ...but the second part pushed it back to 0.003 + min_wait
+    assert not d.flush and clk.t + d.wait_s == pytest.approx(0.008)
+    clk.advance(d.wait_s)
+    d = w.observe(40)
+    assert d.flush and d.trigger == "quiet"
+    assert w.close() == pytest.approx(0.008)
+
+
+def test_window_steady_growth_never_goes_quiet():
+    clk = FakeClock()
+    w = MicroBatchWindow(clock=clk, min_wait_s=0.005, max_wait_s=0.05,
+                         target_bucket=256)
+    depth = 9
+    while clk.t < 0.05:  # a look every 4 ms, each three pods deeper
+        d = w.observe(depth)
+        assert not d.flush and d.wait_s <= 0.005 + 1e-12
+        clk.advance(0.004)
+        depth += 3
+    d = w.observe(depth)
+    assert d.flush and d.trigger == "max-wait"
+
+
+def test_window_below_min_bucket_still_waits_to_max_wait():
+    clk = FakeClock()
+    w = MicroBatchWindow(clock=clk, min_wait_s=0.005, max_wait_s=0.05,
+                         target_bucket=256)
+    w.observe(5)
+    clk.advance(0.02)  # quiet for 4 min_waits, but 5 pods pad to 8 anyway
+    d = w.observe(5)
+    assert not d.flush and d.wait_s == pytest.approx(0.03)
+    clk.advance(d.wait_s)
+    d = w.observe(5)
+    assert d.flush and d.trigger == "max-wait"
+
+
 # ---------------------------------------------------------------------------
 # e2e admission-to-bind latency threading
 # ---------------------------------------------------------------------------
@@ -695,6 +756,38 @@ def test_legacy_run_skips_solve_while_idle(monkeypatch):
     assert not t.is_alive()
 
 
+def test_serving_loop_flushes_ended_burst_quiet():
+    """A 40-pod burst ingested whole through the real doorbell flushes
+    once it stops growing, not at max_wait."""
+    s = _scheduler(n_nodes=8,
+                   warmup=WarmupConfig(enabled=True, pod_buckets=(64,)))
+    s.warmup(sample_pods=[make_pod("w", cpu_milli=50,
+                                   memory=128 * 2**20)])
+    bell = s.attach_doorbell(Doorbell())
+    cfg = ServingConfig(enabled=True, min_wait_s=0.005, max_wait_s=1.0,
+                        target_bucket=256)
+    loop = ServingLoop(s, bell, cfg)
+
+    def burst():
+        for i in range(40):
+            s.on_pod_add(make_pod(f"b-{i}", cpu_milli=50,
+                                  memory=128 * 2**20))
+
+    loop.ingest(burst)
+    results = []
+    deadline = time.monotonic() + 10
+    while not results and time.monotonic() < deadline:
+        res = loop.run_once()
+        if res is not None:
+            results.append(res)
+    assert loop.cycles == 1 and len(results) == 1
+    res = results[0]
+    assert res.flush_trigger == "quiet" and res.scheduled == 40
+    assert res.window_s < cfg.max_wait_s / 4
+    assert s.metrics.microbatch_flushes.value(trigger="quiet") == 1
+    assert s.metrics.microbatch_flushes.value(trigger="max-wait") == 0
+
+
 def test_serving_loop_churn_smoke():
     """~2 s of synthetic create/delete churn through the event-driven
     serving loop end-to-end: everything binds, windows flush on both
@@ -747,10 +840,11 @@ def test_serving_loop_churn_smoke():
     assert total_bound == created, (total_bound, created)
     assert len(s.queue) == 0
     # micro-batch provenance made it through
-    assert all(r.flush_trigger in ("bucket-fill", "max-wait")
+    assert all(r.flush_trigger in ("bucket-fill", "quiet", "max-wait")
                for r in results)
     assert s.metrics.microbatch_flushes.value(trigger="max-wait") \
         + s.metrics.microbatch_flushes.value(trigger="bucket-fill") \
+        + s.metrics.microbatch_flushes.value(trigger="quiet") \
         == len(results)
     # per-pod create-to-bind latencies are bounded by window + solve
     lats = [v for r in results for v in r.e2e_latency_s.values()]
