@@ -154,6 +154,7 @@ _SYNC_CALLS = {
 #: deliberately NOT in this set — see docs/lint.md.
 HOT_FUNC_NAMES = {
     "Scheduler._run_tier", "Scheduler._solve_ladder", "Scheduler._exact_solve",
+    "Scheduler._solve",
     "validate_solution", "greedy_assign", "batch_assign",
 }
 

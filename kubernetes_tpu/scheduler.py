@@ -33,7 +33,7 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Callable, Dict, List, Optional, Protocol, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Protocol, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +86,73 @@ def _static_vol_pass(dp, dn, ds, dv):
     from kubernetes_tpu.ops.predicates import static_volume_reasons
 
     return static_volume_reasons(dp, dn, ds, dv)
+
+
+#: ladder tiers that re-pin every operand onto one device (the mesh
+#: ladder's single-device rung and the host-backend fallback)
+_REPIN_TIERS = ("batch-single", "batch-cpu")
+#: routes that solve a (P, C) candidate frame, not the dense plane
+_FRAME_TIERS = ("restricted", "partitioned")
+
+
+class SolveKey(NamedTuple):
+    """Which compiled solve program a cycle runs: the scheduler's solver
+    settings and the cycle's host-side gates (ops/priorities.
+    solver_gates), fixed once per cycle and once per warmup sweep.
+    Every route and every warmup site reads the solver's keywords and
+    its retrace signature from here, so the served and the warmed
+    signatures cannot drift apart."""
+
+    solver: str
+    skip_prio: tuple
+    no_ports: bool
+    no_pod_aff: bool
+    no_spread: bool
+    pred_mask: Optional[int]
+    per_node_cap: int
+    max_rounds: int
+    weights: Optional[dict]
+    mesh_live: bool
+    #: Sinkhorn convergence telemetry rides the solve
+    stats: bool
+    #: early-exit tolerance of a warm-started frame solve (None = cold)
+    warm_tol: Optional[float]
+
+    def signature(self, frame: bool, no_mask: bool, no_score: bool,
+                  no_init: bool) -> tuple:
+        """The static half of the ``solve`` site's retrace digest.
+        extra_mask/extra_score None-ness joins it because a clean batch
+        routes to the fused lean round path (ops/assign.py), a different
+        compiled program; mesh liveness joins it because sharding is
+        part of XLA's compile key but invisible to the shape digest. A
+        frame solve forces the port/topology gates on and keys on its
+        warm-start operand instead."""
+        if frame:
+            return ("restricted", self.solver, self.skip_prio,
+                    self.pred_mask, self.per_node_cap, self.max_rounds,
+                    no_init, no_score, self.mesh_live)
+        return (self.solver, self.skip_prio, self.no_ports,
+                self.no_pod_aff, self.no_spread, self.pred_mask,
+                self.per_node_cap, self.max_rounds, no_mask, no_score,
+                self.mesh_live)
+
+    def batch_kwargs(self, tier: str) -> dict:
+        """batch_assign's static keywords on one tier — also what the
+        AOT cost and memory analyses lower. The re-pinning tiers solve
+        without the transport plan and without its telemetry."""
+        if tier in _FRAME_TIERS:
+            sk = self.solver == "sinkhorn"
+            gates, stats = (True, True, True), self.stats and sk
+        else:
+            sk = tier == "sinkhorn"
+            gates = (self.no_ports, self.no_pod_aff, self.no_spread)
+            stats = self.stats and tier not in _REPIN_TIERS
+        return dict(max_rounds=self.max_rounds,
+                    per_node_cap=self.per_node_cap,
+                    enabled_mask=self.pred_mask, use_sinkhorn=sk,
+                    skip_priorities=self.skip_prio, no_ports=gates[0],
+                    no_pod_affinity=gates[1], no_spread=gates[2],
+                    stats_out=stats)
 
 
 class Binder(Protocol):
@@ -1283,8 +1350,6 @@ class Scheduler:
         latency incident can distinguish window time from solve time."""
         from kubernetes_tpu.ops.assign import (
             _apply_batch,
-            batch_assign,
-            greedy_assign,
             nodes_with_usage,
             usage_from_nodes,
         )
@@ -1391,7 +1456,7 @@ class Scheduler:
             # for port-free batches (static jit keys;
             # ops/priorities.empty_priorities,
             # ops/predicates.pods_have_no_ports)
-            skip_prio, no_ports, no_pod_aff, no_spread = solver_gates(nt, pt)
+            gates = solver_gates(nt, pt)
             # mesh liveness for THIS cycle: resident snapshots come back
             # already sharded (cache.set_mesh); the legacy per-cycle
             # host pack re-places onto the mesh below; only the device-
@@ -1400,6 +1465,7 @@ class Scheduler:
             self._mesh_live = (self.mesh is not None
                                and (dn is not None
                                     or not self.device_resident_snapshot))
+            key = self._solve_key(self.solver, gates)
             self.obs.note_mesh_cycle(
                 int(self.mesh.devices.size) if self._mesh_live else 0)
             if dn is None:
@@ -1531,7 +1597,7 @@ class Scheduler:
             # nominated pods — _pipeline_eligible)
             return self._pipelined_tail(
                 batch, cycle, res, t0, trace, nt, dn, ds, dt, node_order,
-                skip_prio, no_ports, no_pod_aff, no_spread, pt.owner_cols,
+                key, pt.owner_cols,
             )
 
         # incremental solve: a steady-state micro-batch on a clean/delta
@@ -1540,11 +1606,10 @@ class Scheduler:
         # A declined/under-placed/invalid attempt falls through to the
         # cold solve below (the correctness fallback).
         if self._incremental_eligible(batch, nominated, dn, dt, dv,
-                                      snap_mode, no_ports, no_pod_aff,
-                                      no_spread, nt):
+                                      snap_mode, key, nt):
             inc_out = self._restricted_tail(
                 batch, cycle, res, t0, trace, nt, dn, ds, dp, node_order,
-                skip_prio)
+                key)
             if inc_out is not None:
                 return inc_out
 
@@ -1557,11 +1622,10 @@ class Scheduler:
         # partitioned attempt that cannot place its whole batch binds
         # nothing and falls through.
         if self._partitioned_cold_eligible(batch, nominated, dn, dt, dv,
-                                           no_ports, no_pod_aff,
-                                           no_spread):
+                                           key):
             cold_out = self._partitioned_cold_tail(
                 batch, cycle, res, t0, trace, nt, dn, ds, dp, node_order,
-                skip_prio)
+                key)
             if cold_out is not None:
                 return cold_out
 
@@ -1679,8 +1743,7 @@ class Scheduler:
             ).mask
             extra_mask = nom_mask if extra_mask is None else (extra_mask & nom_mask)
 
-        solver = self.solver
-        if solver == "exact":
+        if key.solver == "exact":
             # The exact Hungarian models capacity as per-node SLOTS only:
             # in-batch coupling through host ports, volumes, or topology
             # terms is not in its constraint matrix, so two co-admitted
@@ -1703,7 +1766,7 @@ class Scheduler:
                 hazards.append("topology")
             if dv is not None:
                 hazards.append("volumes")
-            if not no_ports:  # host-side gate already knows; no device sync
+            if not key.no_ports:  # host-side gate knows; no device sync
                 hazards.append("host-ports")
             if hazards:
                 self.exact_fallbacks += 1
@@ -1713,31 +1776,10 @@ class Scheduler:
                     f"exact solver unsafe with {'+'.join(hazards)}; "
                     "using round solver"
                 )
-                solver = "batch"
-        # retrace telemetry: classify this solve's abstract signature at
-        # the host boundary BEFORE the jitted call — a new signature at a
-        # warmed site means XLA recompiles underneath (zero host syncs:
-        # the digest reads shape/dtype metadata only)
-        self.obs.jax.record_call(
-            "solve", dp, dn, ds, dt, dv,
-            # extra_mask/extra_score None-ness joins the digest: a clean
-            # batch routes to the fused lean round path (ops/assign.py),
-            # a different compiled program than the extender/plugin-fed
-            # one — without the flags an alternation would recompile
-            # invisibly to the retrace telemetry
-            static=(solver, tuple(skip_prio), no_ports, no_pod_aff,
-                    no_spread, self.pred_mask, self.per_node_cap,
-                    self.max_rounds, extra_mask is None,
-                    extra_score is None,
-                    # mesh liveness joins the digest: sharding is part
-                    # of XLA's compile key but invisible to the shape/
-                    # dtype digest — a cooloff flip to single-device
-                    # would otherwise recompile unseen by the telemetry
-                    self._mesh_live),
-        )
+                key = key._replace(solver="batch")
         ladder = self._solve_ladder(
-            solver, batch, dp, dn, ds, dt, dv, sv, base_fr, extra_mask,
-            extra_score, skip_prio, no_ports, no_pod_aff, no_spread, res,
+            key, batch, dp, dn, ds, dt, dv, sv, base_fr, extra_mask,
+            extra_score, res, record=True,
         )
         if ladder is None:
             # every tier failed (even the in-process oracle — a total
@@ -1797,14 +1839,7 @@ class Scheduler:
         # usage + final assignment, gang rollbacks applied) so it
         # executes while the host binds; its ~28 B vector is read back
         # after the bind loop alongside the failure readbacks
-        q_dev = None
-        if self.scenario_pack is not None and self.scenario.quality:
-            from kubernetes_tpu.ops.scenario_cost import quality_reduce
-
-            pad_a = np.full((dp.valid.shape[0],), -1, np.int32)
-            pad_a[: len(batch)] = assigned
-            q_dev = quality_reduce(jnp.asarray(pad_a), usage.requested,
-                                   dp, dn)
+        q_dev = self._quality_dispatch(assigned, usage, dp, dn)
 
         res.rounds = int(rounds)
         solve_s = trace.total_s()
@@ -1902,15 +1937,7 @@ class Scheduler:
         trace.step(f"bound {res.scheduled}, failed {res.unschedulable}")
 
         if q_dev is not None:
-            with self.obs.span("pipeline:readback@quality"):
-                qvec = self.obs.jax.readback("scenario-quality", q_dev)
-            from kubernetes_tpu.scenarios.quality import decode_quality
-
-            quality = decode_quality(qvec)
-            quality.update(
-                self.scenario_pack.quality_host(batch, assigned, nt))
-            res.scenario_quality = quality
-            self._publish_scenario_quality(quality)
+            self._quality_read(q_dev, res, batch, assigned, nt)
 
         # schedulability explainer: decode the read-back reduction into
         # the cycle's UnschedulableReport — every _fail'd pod gets a row
@@ -2141,37 +2168,92 @@ class Scheduler:
                 f"circuit breaker for {target} closed; full service restored",
             )
 
-    def _run_tier(self, tier, batch, dp, dn, ds, dt, dv, sv, base_fr,
-                  extra_mask, extra_score, skip_prio, no_ports, no_pod_aff,
-                  no_spread):
+    def _solve_key(self, solver: str, gates) -> SolveKey:
+        """The solve key of this cycle (or warmup sweep): ``solver`` and
+        ``gates`` — solver_gates' (skip_priorities, no_ports,
+        no_pod_affinity, no_spread) — plus the scheduler's settings and
+        the current mesh liveness."""
+        skip_prio, no_ports, no_pod_aff, no_spread = gates
+        inc = self.incremental
+        return SolveKey(
+            solver, tuple(skip_prio), no_ports, no_pod_aff, no_spread,
+            self.pred_mask, self.per_node_cap, self.max_rounds,
+            self.weights, self._mesh_live,
+            bool(self.obs.config.sinkhorn_telemetry),
+            inc.warm_tol if (inc.warm_potentials and solver == "sinkhorn")
+            else None)
+
+    def _solve(self, key: SolveKey, tier: str, dp, dn, ds, *, topo=None,
+               vol=None, static_vol=None, extra_mask=None, extra_score=None,
+               base_fr=None, sk_init=None, n_pods: int = 0,
+               record: bool = True, warmup: bool = False):
+        """THE solver call of every cycle route, every warmup site and
+        the cost-model anchor. ``tier`` picks the solver and names the
+        fault site ``solve:<tier>``: greedy, exact, a dense batch tier,
+        or a candidate-frame route (restricted, partitioned — ``dn`` is
+        then the gathered frame). ``record`` first classifies the call's
+        abstract signature with the retrace telemetry, at the host
+        boundary before the jitted call (a new signature at a warmed
+        site means XLA recompiles underneath; the digest reads
+        shape/dtype metadata only). ``warmup`` registers it as an
+        ahead-of-time compile and leaves the fault hook and the
+        Sinkhorn telemetry out.
+
+        Returns ``(assigned, usage, rounds)`` — plus the final
+        potentials of a warm-started frame solve; the greedy oracle
+        reports ``n_pods`` rounds."""
+        from kubernetes_tpu.ops.assign import batch_assign, greedy_assign
+
+        frame = tier in _FRAME_TIERS
+        if record:
+            trees = (dp, dn, ds) if frame else (dp, dn, ds, topo, vol)
+            self.obs.jax.record_call("solve", *trees, static=key.signature(
+                frame, extra_mask is None, extra_score is None,
+                sk_init is None), warmup=warmup)
+        hook = (self.fault_injector.solver_hook
+                if self.fault_injector is not None and not warmup else None)
+        site = f"solve:{tier}"
+        if tier == "exact":
+            out = self._exact_solve(dp, dn, ds, topo, base_fr, extra_mask,
+                                    extra_score)
+            return out if hook is None else hook(site, *out,
+                                                 dn.valid.shape[0])
+        if tier == "greedy":
+            a, u = greedy_assign(
+                dp, dn, ds, key.weights, topo=topo, extra_mask=extra_mask,
+                vol=vol, static_vol=static_vol, enabled_mask=key.pred_mask,
+                extra_score=extra_score, skip_priorities=key.skip_prio,
+                no_ports=key.no_ports, no_pod_affinity=key.no_pod_aff,
+                no_spread=key.no_spread, fault_hook=hook, fault_site=site)
+            return a, u, n_pods
+        kw = key.batch_kwargs(tier)
+        if frame:
+            kw.update(sk_init=sk_init, sk_tol=key.warm_tol,
+                      potentials_out=key.warm_tol is not None)
+        out = batch_assign(
+            dp, dn, ds, key.weights, topo=topo, extra_mask=extra_mask,
+            vol=vol, static_vol=static_vol, extra_score=extra_score,
+            fault_hook=hook, fault_site=site, **kw)
+        if kw["stats_out"]:
+            # sinkhorn convergence telemetry rides the solve as a (2,)
+            # device pair (stays on device; obs reads it back once at
+            # cycle end)
+            if not warmup:
+                self.obs.note_sinkhorn(out[3])
+            out = out[:3] + out[4:]
+        return out
+
+    def _run_tier(self, key, tier, batch, dp, dn, ds, dt, dv, sv, base_fr,
+                  extra_mask, extra_score, record):
         """One solve attempt on one ladder tier. Returns
         ``((assigned, usage, rounds), dp_used, dn_used)`` — the re-
         pinning tiers (batch-single, batch-cpu) hand back the tables
         they actually solved against, so the fused validator never
         mixes a single-device result with mesh-sharded tables in one
         jitted call. Exceptions propagate to the ladder."""
-        from kubernetes_tpu.ops.assign import batch_assign, greedy_assign
-
-        hook = (self.fault_injector.solver_hook
-                if self.fault_injector is not None else None)
-        if tier == "greedy":
-            a, u = greedy_assign(
-                dp, dn, ds, self.weights, topo=dt, extra_mask=extra_mask,
-                vol=dv, static_vol=sv, enabled_mask=self.pred_mask,
-                extra_score=extra_score, skip_priorities=skip_prio,
-                no_ports=no_ports, no_pod_affinity=no_pod_aff,
-                no_spread=no_spread, fault_hook=hook,
-                fault_site="solve:greedy",
-            )
-            return (a, u, len(batch)), dp, dn
-        if tier == "exact":
-            out = self._exact_solve(
-                dp, dn, ds, dt, base_fr, extra_mask, extra_score
-            )
-            if hook is not None:
-                out = hook("solve:exact", *out, dn.valid.shape[0])
-            return out, dp, dn
-        if tier in ("batch-single", "batch-cpu"):
+        ops = dict(topo=dt, extra_mask=extra_mask, vol=dv, static_vol=sv,
+                   extra_score=extra_score)
+        if tier in _REPIN_TIERS:
             if tier == "batch-single":
                 # mesh-ladder rung: the identical solve re-pinned onto
                 # ONE device of the mesh — survives a sick collective /
@@ -2191,54 +2273,38 @@ class Scheduler:
                 return (None if t is None else jax.tree_util.tree_map(
                     lambda x: jax.device_put(x, one), t))
 
-            dp_p, dn_p = put(dp), put(dn)
-            out = batch_assign(
-                dp_p, dn_p, put(ds), self.weights,
-                max_rounds=self.max_rounds, per_node_cap=self.per_node_cap,
-                topo=put(dt), extra_mask=put(extra_mask), vol=put(dv),
-                static_vol=put(sv), enabled_mask=self.pred_mask,
-                extra_score=put(extra_score), use_sinkhorn=False,
-                skip_priorities=skip_prio, no_ports=no_ports,
-                no_pod_affinity=no_pod_aff, no_spread=no_spread,
-                fault_hook=hook, fault_site=f"solve:{tier}",
-            )
-            return out, dp_p, dn_p
-        # sinkhorn convergence telemetry rides the solve as a (2,) device
-        # pair (stays on device; obs reads it back once at cycle end)
-        want_stats = self.obs.config.sinkhorn_telemetry
-        out = batch_assign(
-            dp, dn, ds, self.weights,
-            max_rounds=self.max_rounds, per_node_cap=self.per_node_cap,
-            topo=dt, extra_mask=extra_mask, vol=dv, static_vol=sv,
-            enabled_mask=self.pred_mask, extra_score=extra_score,
-            use_sinkhorn=(tier == "sinkhorn"), skip_priorities=skip_prio,
-            no_ports=no_ports, no_pod_affinity=no_pod_aff,
-            no_spread=no_spread, fault_hook=hook,
-            fault_site=f"solve:{tier}", stats_out=want_stats,
-        )
-        if want_stats:
-            assigned, usage, rounds, sk_stats = out
-            self.obs.note_sinkhorn(sk_stats)
-            return (assigned, usage, rounds), dp, dn
+            dp, dn, ds = put(dp), put(dn), put(ds)
+            ops = {k: put(v) for k, v in ops.items()}
+        out = self._solve(key, tier, dp, dn, ds, base_fr=base_fr,
+                          n_pods=len(batch), record=record, **ops)
         return out, dp, dn
 
-    def _validated_readback(self, tier, out, dp, dn):
-        """Validate one tier's result and read it back as ONE d2h
-        transfer — the fused solve+validate boundary. The verdict is
-        computed ON DEVICE (ops/assign.device_validate: range /
-        invalid-node / finiteness / capacity recomputation, never
-        trusting the solver's claimed usage) and rides the same readback
-        as the assignment and round count, so a healthy cycle's solve
-        path syncs exactly once. The host checker
+    def _validated_readback(self, tier, out, dp, dn, cand=None, extra=None,
+                            site="solve-result"):
+        """Validate one solve's result and read it back as ONE d2h
+        transfer — the fused solve+validate boundary of every route.
+        The verdict is computed ON DEVICE (ops/assign.device_validate:
+        range / invalid-node / finiteness / capacity recomputation,
+        never trusting the solver's claimed usage) and rides the same
+        readback as the assignment and round count, so a healthy
+        cycle's solve path syncs exactly once. The host checker
         (ops/assign.validate_solution) remains the trust floor: it takes
         over when the result can't even reach the device (shape),
         whenever ``robustness.host_validate`` forces it, and as the
         parity oracle in tests/test_fused_validate.py.
 
-        Returns ``(assigned_host, usage_dev, rounds_int)`` or raises
-        SolverResultInvalid with the same reason vocabulary the host
-        checker uses (the verdict gates binding exactly as before)."""
+        A frame route passes its candidate column map ``cand``: the
+        frame-local answer is validated against the frame ``dn`` and
+        mapped to global node rows on device before the readback.
+        ``extra`` device entries ride the same readback under the
+        readback site ``site``.
+
+        Returns ``(assigned_host, usage_dev, rounds_int, extra_host)``
+        or raises SolverResultInvalid with the same reason vocabulary
+        the host checker uses (the verdict gates binding exactly as
+        before)."""
         from kubernetes_tpu.faults import SolverResultInvalid
+        from kubernetes_tpu.ops.arrays import map_restricted_assignment
         from kubernetes_tpu.ops.assign import (
             VALIDATE_REASONS,
             device_validate,
@@ -2246,44 +2312,40 @@ class Scheduler:
         )
 
         rc = self.robustness
-        a_dev, u_dev, rounds = out
+        a_dev, u_dev, rounds = out[:3]
+
+        def reject(why):
+            self.metrics.solver_rejections.inc(tier=tier, reason=why)
+            raise SolverResultInvalid(f"{tier}: {why}")
+
         dv_out = None
-        if rc.validate_results and not rc.host_validate:
+        if rc.validate_results:
             with self.obs.span("validate"):
-                dv_out = device_validate(a_dev, u_dev, dp, dn,
-                                         self.pred_mask)
+                if not rc.host_validate:
+                    dv_out = device_validate(a_dev, u_dev, dp, dn,
+                                             self.pred_mask)
                 if dv_out is None:
-                    # not array-shaped enough to reach the device: the
-                    # host checker renders the verdict (shape/dtype)
+                    # forced, or not array-shaped enough to reach the
+                    # device: the host checker renders the verdict
                     ok, why = validate_solution(a_dev, u_dev, dp, dn,
                                                 self.pred_mask)
                     if not ok:
-                        self.metrics.solver_rejections.inc(
-                            tier=tier, reason=why)
-                        raise SolverResultInvalid(f"{tier}: {why}")
-        elif rc.validate_results:
-            with self.obs.span("validate"):
-                ok, why = validate_solution(a_dev, u_dev, dp, dn,
-                                            self.pred_mask)
-                if not ok:
-                    self.metrics.solver_rejections.inc(tier=tier,
-                                                       reason=why)
-                    raise SolverResultInvalid(f"{tier}: {why}")
-        payload = {"assigned": a_dev, "rounds": rounds}
+                        reject(why)
+        payload = dict(extra or {}, rounds=rounds, assigned=(
+            a_dev if cand is None
+            else map_restricted_assignment(a_dev, cand)))
         if dv_out is not None:
             payload["code"], payload["valid"] = dv_out
-        host = self.obs.jax.readback("solve-result", payload)
+        host = self.obs.jax.readback(site, payload)
         code = int(host.get("code", 0))
         if code:
-            why = VALIDATE_REASONS[code]
-            self.metrics.solver_rejections.inc(tier=tier, reason=why)
-            raise SolverResultInvalid(f"{tier}: {why}")
+            reject(VALIDATE_REASONS[code])
         # device_get already materialized host["assigned"] as numpy
-        return host["assigned"], u_dev, int(host["rounds"])
+        return (host["assigned"], u_dev, int(host["rounds"]),
+                {k: host[k] for k in (extra or ())})
 
-    def _solve_ladder(self, solver, batch, dp, dn, ds, dt, dv, sv, base_fr,
-                      extra_mask, extra_score, skip_prio, no_ports,
-                      no_pod_aff, no_spread, res):
+    def _solve_ladder(self, key, batch, dp, dn, ds, dt, dv, sv, base_fr,
+                      extra_mask, extra_score, res, record=False):
         """The degradation ladder: try the configured solver tier, then
         each tier of ``robustness.fallback_chain`` (TPU batch → CPU-JAX
         batch → the greedy sequential oracle), with per-tier circuit
@@ -2292,10 +2354,10 @@ class Scheduler:
         infeasible pod. Returns (assigned_host, usage, rounds, tier) —
         the assignment ALREADY read back (one fused d2h transfer, see
         :meth:`_validated_readback`) — or None when every tier failed
-        (the caller requeues the whole batch)."""
-        from kubernetes_tpu.faults import SolverResultInvalid
-
+        (the caller requeues the whole batch). ``record`` registers the
+        cycle's solve signature once, on the first attempt."""
         rc = self.robustness
+        solver = key.solver
         tiers = [solver]
         if self._mesh_live and solver in ("batch", "sinkhorn", "greedy"):
             # the mesh-aware rung: a failing SHARDED solve retries on
@@ -2342,11 +2404,11 @@ class Scheduler:
             for attempt in range(attempts):
                 ts = self.clock()
                 with self.obs.span(f"solve:{tier}", attempt=attempt):
+                    rec, record = record, False
                     try:
                         out, dp_t, dn_t = self._run_tier(
-                            tier, batch, dp, dn, ds, dt, dv, sv, base_fr,
-                            extra_mask, extra_score, skip_prio, no_ports,
-                            no_pod_aff, no_spread,
+                            key, tier, batch, dp, dn, ds, dt, dv, sv,
+                            base_fr, extra_mask, extra_score, rec,
                         )
                         # fused validate + single readback (raises
                         # SolverResultInvalid on a lying solver, exactly
@@ -2602,8 +2664,7 @@ class Scheduler:
         return ladder[-1]
 
     def _incremental_eligible(self, batch, nominated, dn, dt, dv,
-                              snap_mode, no_ports, no_pod_aff, no_spread,
-                              nt) -> bool:
+                              snap_mode, key, nt) -> bool:
         """May THIS cycle take the restricted solve? The gates mirror
         the fused lean route's trace-time facts (whole-batch host
         coupling and cross-node constraint classes need the full plane)
@@ -2653,9 +2714,9 @@ class Scheduler:
         # ports/volumes couple in-batch per node (excluded outright);
         # topology masks reduce over whole topology groups — only safe
         # to drop when the batch-scoped gates prove them vacuous
-        if dv is not None or not no_ports:
+        if dv is not None or not key.no_ports:
             return False
-        if dt is not None and not (no_pod_aff and no_spread):
+        if dt is not None and not (key.no_pod_aff and key.no_spread):
             return False
         # the tuner observes the RAW batch size BEFORE the C compare:
         # batches bounced for being too big are exactly the evidence
@@ -2674,7 +2735,7 @@ class Scheduler:
         return True
 
     def _restricted_tail(self, batch, cycle, res, t0, trace, nt, dn, ds,
-                         dp, node_order, skip_prio):
+                         dp, node_order, key):
         """The incremental cycle's solve + bind tail: pick candidate
         columns from the cached score plane (O(N log C) — the only
         full-N work), gather them into a (P, C) view, solve with the
@@ -2686,17 +2747,7 @@ class Scheduler:
         need the full plane) and any solve/validation error return None
         so the caller re-solves cold — the PR-1 ladder's correctness
         fallback, unchanged."""
-        from kubernetes_tpu.faults import SolverResultInvalid
-        from kubernetes_tpu.ops.arrays import (
-            gather_candidates,
-            map_restricted_assignment,
-        )
-        from kubernetes_tpu.ops.assign import (
-            VALIDATE_REASONS,
-            batch_assign,
-            device_validate,
-            validate_solution,
-        )
+        from kubernetes_tpu.ops.arrays import gather_candidates
 
         inc = self.incremental
         # gang minMember pre-check (host ints only): a group that cannot
@@ -2735,18 +2786,13 @@ class Scheduler:
             reuse = 0.0
         else:
             reuse = max(0.0, 1.0 - len(idxs) / max(nt.n, 1))
-        rc = self.robustness
-        use_sk = self.solver == "sinkhorn"
-        want_stats = bool(self.obs.config.sinkhorn_telemetry and use_sk)
-        warm = bool(inc.warm_potentials and use_sk)
+        warm = key.warm_tol is not None
         gen = getattr(self.cache, "summary_generation", 0)
         pot_key = (dp.valid.shape[0], C, gen)
         sk_init = None
         if warm and self._sk_warm_pot is not None \
                 and self._sk_warm_pot[0] == pot_key:
             sk_init = self._sk_warm_pot[1]
-        hook = (self.fault_injector.solver_hook
-                if self.fault_injector is not None else None)
         # mesh-sharded candidate pick: per-shard local top-C over the
         # node-sharded resident plane, replicated merge of the (S, C)
         # winners — bit-identical to the single-pass pick (the parity
@@ -2785,65 +2831,21 @@ class Scheduler:
                     with self.obs.span("scenario:cost"):
                         extra_score = self.scenario_pack.cost(
                             batch, nt, node_order, dp, sub_dn)
-                self.obs.jax.record_call(
-                    "solve", dp, sub_dn, ds,
-                    static=("restricted", self.solver, tuple(skip_prio),
-                            self.pred_mask, self.per_node_cap,
-                            self.max_rounds, sk_init is None,
-                            extra_score is None, self._mesh_live),
-                )
-                out = batch_assign(
-                    dp, sub_dn, ds, self.weights,
-                    max_rounds=self.max_rounds,
-                    per_node_cap=self.per_node_cap,
-                    enabled_mask=self.pred_mask, use_sinkhorn=use_sk,
-                    extra_score=extra_score,
-                    skip_priorities=skip_prio, no_ports=True,
-                    no_pod_affinity=True, no_spread=True,
-                    fault_hook=hook, fault_site="solve:restricted",
-                    stats_out=want_stats,
-                    sk_init=sk_init,
-                    sk_tol=(inc.warm_tol if warm else None),
-                    potentials_out=warm,
-                )
-                a_local, u_local, rounds = out[0], out[1], out[2]
-                k = 3
-                if want_stats:
-                    self.obs.note_sinkhorn(out[k])
-                    k += 1
-                potentials = out[k] if warm else None
+                out = self._solve(key, "restricted", dp, sub_dn, ds,
+                                  extra_score=extra_score, sk_init=sk_init)
+                a_local, u_local = out[0], out[1]
+                potentials = out[3] if warm else None
                 # placement-rank telemetry: the deepest candidate-frame
                 # position any pod placed into, reduced to ONE device
                 # scalar riding the existing solve-result readback (a
                 # (P,) position vector would cost +4 B/pod and breach
                 # the answer-sized budget). The auto-tuner reads it to
                 # decide when the frame is running hot.
-                payload = {"rounds": rounds,
-                           "depth": jnp.max(jnp.where(
-                               dp.valid & (a_local >= 0), a_local,
-                               jnp.int32(-1)))}
-                dv_out = None
-                if rc.validate_results and not rc.host_validate:
-                    with self.obs.span("validate"):
-                        dv_out = device_validate(a_local, u_local, dp,
-                                                 sub_dn, self.pred_mask)
-                    if dv_out is not None:
-                        payload["code"], payload["valid"] = dv_out
-                if rc.validate_results and dv_out is None:
-                    # host trust floor (host_validate / unshippable
-                    # result): same checker, candidate-local frame
-                    ok, why = validate_solution(a_local, u_local, dp,
-                                                sub_dn, self.pred_mask)
-                    if not ok:
-                        raise SolverResultInvalid(f"restricted: {why}")
-                payload["assigned"] = map_restricted_assignment(
-                    a_local, cand)
-                host = self.obs.jax.readback("solve-result", payload)
-                code = int(host.get("code", 0))
-                if code:
-                    raise SolverResultInvalid(
-                        f"restricted: {VALIDATE_REASONS[code]}")
-                assigned = host["assigned"]
+                depth = jnp.max(jnp.where(dp.valid & (a_local >= 0),
+                                          a_local, jnp.int32(-1)))
+                assigned, _, rounds, ext = self._validated_readback(
+                    "restricted", out, dp, sub_dn, cand=cand,
+                    extra={"depth": depth})
         except Exception as e:
             # ANY restricted failure — a lying solver, a device error,
             # a validation verdict — declines the attempt; the caller
@@ -2856,7 +2858,7 @@ class Scheduler:
                 m.inc(scope="declined")
             return None
         self._tuner_depth_max = max(self._tuner_depth_max,
-                                    int(host.get("depth", -1)) + 1)
+                                    int(ext["depth"]) + 1)
         placed = assigned[: len(batch)]
         if (placed < 0).any():
             # a pod the candidate set could not place might fit on a
@@ -2891,13 +2893,8 @@ class Scheduler:
         # nodes_used/gang stats are exact; the capacity-shaped scores
         # are frame-local — docs/scenarios.md). Dispatched now so the
         # device works while the host binds, read back after.
-        q_dev = None
-        if self.scenario_pack is not None and self.scenario.quality:
-            from kubernetes_tpu.ops.scenario_cost import quality_reduce
-
-            q_dev = quality_reduce(a_local.astype(jnp.int32),
-                                   u_local.requested, dp, sub_dn)
-        res.rounds = int(host["rounds"])
+        q_dev = self._quality_dispatch(a_local, u_local, dp, sub_dn)
+        res.rounds = rounds
         res.solver_tier = self.solver
         res.solve_scope = "restricted"
         res.reuse_frac = round(reuse, 4)
@@ -2911,14 +2908,7 @@ class Scheduler:
         trace.end_span(bind_span)
         trace.step(f"bound {res.scheduled}, failed {res.unschedulable}")
         if q_dev is not None:
-            qvec = self.obs.jax.readback("scenario-quality", q_dev)
-            from kubernetes_tpu.scenarios.quality import decode_quality
-
-            quality = decode_quality(qvec)
-            quality.update(
-                self.scenario_pack.quality_host(batch, assigned, nt))
-            res.scenario_quality = quality
-            self._publish_scenario_quality(quality)
+            self._quality_read(q_dev, res, batch, assigned, nt)
         if getattr(self.obs.config, "explain", True):
             # no filter-pass failures by construction (everything
             # placed), but admission-tail failures still get report
@@ -2939,8 +2929,7 @@ class Scheduler:
         return max(min(b, n_pad // max(C, 1)), 0)
 
     def _partitioned_cold_eligible(self, batch, nominated, dn, dt, dv,
-                                   no_ports, no_pod_aff,
-                                   no_spread) -> bool:
+                                   key) -> bool:
         """May THIS cycle take the PARTITIONED cold solve (sparsity-
         first primary mode)? Engages when the restricted warm route did
         not take the cycle — a full-snapshot rebuild, an oversized
@@ -2972,16 +2961,16 @@ class Scheduler:
             return False
         if any(p.pod_group for p in batch):
             return False
-        if dv is not None or not no_ports:
+        if dv is not None or not key.no_ports:
             return False
-        if dt is not None and not (no_pod_aff and no_spread):
+        if dt is not None and not (key.no_pod_aff and key.no_spread):
             return False
         n_pad = dn.valid.shape[0]
         C = self._candidate_bucket(n_pad)
         return C < n_pad and self._cold_blocks(n_pad, C) >= 2
 
     def _partitioned_cold_tail(self, batch, cycle, res, t0, trace, nt,
-                               dn, ds, dp, node_order, skip_prio):
+                               dn, ds, dp, node_order, key):
         """The partitioned cold solve: rank every column once (the only
         full-N work), deal the top B·C columns round-robin into B
         capacity-balanced blocks of WIDTH C — the restricted path's
@@ -2997,17 +2986,12 @@ class Scheduler:
         ladder, which owns failure analytics and preemption. Cold cost:
         O(N log(B·C)) selection + (≤ B + 1) fixed (P, C) solves —
         sublinear in N, vs the dense plane's O(P·N)."""
-        from kubernetes_tpu.faults import SolverResultInvalid
         from kubernetes_tpu.ops.arrays import (
             gather_candidates,
             gather_node_rows,
-            map_restricted_assignment,
         )
         from kubernetes_tpu.ops.assign import (
-            VALIDATE_REASONS,
             _apply_batch,
-            batch_assign,
-            device_validate,
             nodes_with_usage,
             usage_from_nodes,
         )
@@ -3016,7 +3000,6 @@ class Scheduler:
             partition_columns,
         )
 
-        inc = self.incremental
         n_pad = dn.valid.shape[0]
         P_pad = dp.valid.shape[0]
         C = self._candidate_bucket(n_pad)
@@ -3029,15 +3012,6 @@ class Scheduler:
             # no live cache (full rebuild just invalidated it): one
             # fresh O(N) summary pass — still nothing (P, N)-shaped
             summary = node_summary(dn, **flags)
-        rc = self.robustness
-        use_sk = self.solver == "sinkhorn"
-        warm = bool(inc.warm_potentials and use_sk)
-        want_stats = bool(self.obs.config.sinkhorn_telemetry and use_sk)
-        hook = (self.fault_injector.solver_hook
-                if self.fault_injector is not None else None)
-        solve_statics = ("restricted", self.solver, tuple(skip_prio),
-                         self.pred_mask, self.per_node_cap,
-                         self.max_rounds, True, True, self._mesh_live)
         self.obs.jax.record_call(
             "partition", summary.rank,
             static=(B, C, n_pad, ns, self._mesh_live))
@@ -3046,41 +3020,20 @@ class Scheduler:
         assigned = np.full((len(batch),), -1, np.int64)
         zeros_dirty = jnp.zeros((n_pad,), bool)
 
-        def solve_frame(dp_f, sub_dn, cand, site):
-            """One (P, C) frame solve + validate + global mapping; ONE
-            readback per frame (the declared cold-block boundary)."""
-            self.obs.jax.record_call("solve", dp_f, sub_dn, ds,
-                                     static=solve_statics)
-            out = batch_assign(
-                dp_f, sub_dn, ds, self.weights,
-                max_rounds=self.max_rounds,
-                per_node_cap=self.per_node_cap,
-                enabled_mask=self.pred_mask, use_sinkhorn=use_sk,
-                skip_priorities=skip_prio, no_ports=True,
-                no_pod_affinity=True, no_spread=True,
-                fault_hook=hook, fault_site="solve:partitioned",
-                stats_out=want_stats,
-                sk_tol=(inc.warm_tol if warm else None),
-                potentials_out=warm,
-            )
-            a_local, u_local, rounds = out[0], out[1], out[2]
-            k = 3
-            if want_stats:
-                self.obs.note_sinkhorn(out[k])
-            payload = {"rounds": rounds}
-            if rc.validate_results and not rc.host_validate:
-                dv_out = device_validate(a_local, u_local, dp_f, sub_dn,
-                                         self.pred_mask)
-                if dv_out is not None:
-                    payload["code"], payload["valid"] = dv_out
-            payload["assigned"] = map_restricted_assignment(a_local,
-                                                            cand)
-            host = self.obs.jax.readback(site, payload)
-            code = int(host.get("code", 0))
-            if code:
-                raise SolverResultInvalid(
-                    f"partitioned: {VALIDATE_REASONS[code]}")
-            return host
+        def solve_frame(valid, sub_dn, cand):
+            """One (P, C) frame solve + validate + global mapping over
+            the still-pending pods; ONE readback per frame (the declared
+            cold-block boundary). Placements accumulate host-side."""
+            dp_f = dp._replace(valid=dp.valid & jnp.asarray(valid))
+            out = self._solve(key, "partitioned", dp_f, sub_dn, ds)
+            got, _, rounds, _ = self._validated_readback(
+                "partitioned", out, dp_f, sub_dn, cand=cand,
+                site="cold-block")
+            res.rounds += rounds
+            for i in range(len(batch)):
+                if pending[i] and got[i] >= 0:
+                    assigned[i] = got[i]
+                    pending[i] = False
 
         try:
             with self.obs.span("solve:partitioned", blocks=B):
@@ -3089,17 +3042,8 @@ class Scheduler:
                 for b in range(B):
                     if not pending[: len(batch)].any():
                         break
-                    dp_b = dp._replace(
-                        valid=dp.valid & jnp.asarray(pending))
-                    sub_dn = gather_node_rows(dn, blocks[b])
-                    host = solve_frame(dp_b, sub_dn, blocks[b],
-                                       "cold-block")
-                    res.rounds += int(host["rounds"])
-                    got = host["assigned"]
-                    for i in range(len(batch)):
-                        if pending[i] and got[i] >= 0:
-                            assigned[i] = got[i]
-                            pending[i] = False
+                    solve_frame(pending, gather_node_rows(dn, blocks[b]),
+                                blocks[b])
                 if pending[: len(batch)].any():
                     # remainder pass: one fresh top-C frame over the
                     # usage-OVERLAID table (every block placement
@@ -3117,18 +3061,9 @@ class Scheduler:
                         "incremental", sum_u.rank,
                         static=(C, n_pad, self._mesh_live, ns, True,
                                 None))
-                    dp_r = dp._replace(
-                        valid=dp.valid & jnp.asarray(pending))
                     cand, sub_dn = gather_candidates(
                         sum_u, zeros_dirty, dn_u, C, num_shards=ns)
-                    host = solve_frame(dp_r, sub_dn, cand,
-                                       "cold-block")
-                    res.rounds += int(host["rounds"])
-                    got = host["assigned"]
-                    for i in range(len(batch)):
-                        if pending[i] and got[i] >= 0:
-                            assigned[i] = got[i]
-                            pending[i] = False
+                    solve_frame(pending, sub_dn, cand)
         except Exception as e:
             # any failure — a lying solver, device error, validation
             # verdict — declines the whole attempt; the dense ladder
@@ -3169,8 +3104,7 @@ class Scheduler:
                                   label=" (partitioned)")
 
     def _pipelined_tail(self, batch, cycle, res, t0, trace, nt, dn, ds, dt,
-                        node_order, skip_prio, no_ports, no_pod_aff,
-                        no_spread, owner_cols) -> CycleResult:
+                        node_order, key, owner_cols) -> CycleResult:
         """Double-buffered pack→solve→readback→bind pipeline over fixed
         sub-batches (SURVEY §7.2 step 9): while chunk k's solve runs on
         device (JAX async dispatch), the host packs chunk k+1 and applies
@@ -3183,11 +3117,7 @@ class Scheduler:
         ``dn`` carries), so a chunk's placements count for the next."""
         import numpy as np
 
-        from kubernetes_tpu.ops.assign import (
-            batch_assign,
-            greedy_assign,
-            nodes_with_usage,
-        )
+        from kubernetes_tpu.ops.assign import nodes_with_usage
         from kubernetes_tpu.ops.arrays import volumes_to_device
         from kubernetes_tpu.ops.predicates import decode_reasons
         from kubernetes_tpu.snapshot import FIXED_RESOURCE_NAMES
@@ -3199,19 +3129,11 @@ class Scheduler:
         self.metrics.pipeline_chunks.inc(len(chunks))
         chunk_pad = bucket_size(C)
         explain_on = getattr(self.obs.config, "explain", True)
-        rc = self.robustness
-        solver = self.solver
+        solver = key.solver
         # a restricted_ok scenario pack's per-column cost term joins
         # each chunk's solve as extra_score (the _pipeline_eligible
-        # capability contract); the statics score flag flips with it so
-        # warmed/monolithic/pipelined signatures stay coherent
+        # capability contract)
         pack = self.scenario_pack
-        statics = (solver, tuple(skip_prio), no_ports, no_pod_aff,
-                   no_spread, self.pred_mask, self.per_node_cap,
-                   self.max_rounds, True,  # no extra mask
-                   pack is None, self._mesh_live)
-        hook = (self.fault_injector.solver_hook
-                if self.fault_injector is not None else None)
 
         dn_cur = dn
         solve_s = 0.0
@@ -3259,40 +3181,13 @@ class Scheduler:
                     sc = pack.cost(chunks[k], nt, node_order, dp_c,
                                    dn_in)
             with self.obs.span(f"pipeline:dispatch@{k}", tier=solver):
-                self.obs.jax.record_call("solve", dp_c, dn_in, ds, dt, dv_c,
-                                         static=statics)
-                if solver == "greedy":
-                    a, u = greedy_assign(
-                        dp_c, dn_in, ds, self.weights, topo=dt, vol=dv_c,
-                        static_vol=sv_c, enabled_mask=self.pred_mask,
-                        extra_score=sc,
-                        skip_priorities=skip_prio, no_ports=no_ports,
-                        no_pod_affinity=no_pod_aff, no_spread=no_spread,
-                        fault_hook=hook, fault_site="solve:greedy",
-                    )
-                    return a, u, len(chunks[k])
-                # stats_out matches the monolithic tier's static key so
-                # warmed/monolithic/pipelined solves share ONE compiled
-                # program per shape; the last chunk's sinkhorn stats ride
-                # to end_cycle like the monolith's single solve
-                want_stats = self.obs.config.sinkhorn_telemetry
-                out = batch_assign(
-                    dp_c, dn_in, ds, self.weights,
-                    max_rounds=self.max_rounds,
-                    per_node_cap=self.per_node_cap, topo=dt, vol=dv_c,
-                    static_vol=sv_c, enabled_mask=self.pred_mask,
-                    extra_score=sc,
-                    use_sinkhorn=(solver == "sinkhorn"),
-                    skip_priorities=skip_prio, no_ports=no_ports,
-                    no_pod_affinity=no_pod_aff, no_spread=no_spread,
-                    fault_hook=hook, fault_site=f"solve:{solver}",
-                    stats_out=want_stats,
-                )
-                if want_stats:
-                    assigned_d, usage_d, rounds_d, sk_stats = out
-                    self.obs.note_sinkhorn(sk_stats)
-                    return assigned_d, usage_d, rounds_d
-                return out
+                # the same key as the monolithic tier, so warmed/
+                # monolithic/pipelined solves share ONE compiled program
+                # per shape; the last chunk's sinkhorn stats ride to
+                # end_cycle like the monolith's single solve
+                return self._solve(key, solver, dp_c, dn_in, ds, topo=dt,
+                                   vol=dv_c, static_vol=sv_c,
+                                   extra_score=sc, n_pods=len(chunks[k]))
 
         def settle(k, packed, out, dn_in):
             """Block on chunk k's result — validated on device, verdict
@@ -3308,7 +3203,7 @@ class Scheduler:
             if out is not None:
                 try:
                     with self.obs.span(f"pipeline:readback@{k}"):
-                        a, u_dev, rounds = self._validated_readback(
+                        a, u_dev, rounds, _ = self._validated_readback(
                             solver, out, dp_c, dn_in)
                     a = a[: len(chunk)].copy()
                     br.record_success()
@@ -3327,10 +3222,8 @@ class Scheduler:
             sc = (pack.cost(chunk, nt, node_order, dp_c, dn_in)
                   if pack is not None else None)
             ladder = self._solve_ladder(
-                solver, chunk, dp_c, dn_in, ds, dt, dv_c, sv_c, None,
-                None, sc, skip_prio, no_ports, no_pod_aff, no_spread,
-                res,
-            )
+                key, chunk, dp_c, dn_in, ds, dt, dv_c, sv_c, None, None,
+                sc, res)
             if ladder is None:
                 for pod in chunk:
                     self._fail(pod, cycle, res, ("SolverUnavailable",))
@@ -4168,6 +4061,41 @@ class Scheduler:
             # the stock path performs must happen here too
             self.queue.move_all_to_active()
 
+    def _quality_dispatch(self, assigned, usage, dp, dn):
+        """THE scenario-quality step, first half: dispatch the device
+        reduction over a final assignment (a host answer sliced to the
+        batch is padded back to the pod bucket as unplaced rows), so it
+        executes while the host binds. Returns the ~28 B device vector,
+        or None when no pack reports quality."""
+        from kubernetes_tpu.ops.scenario_cost import quality_reduce
+
+        if self.scenario_pack is None or not self.scenario.quality:
+            return None
+        n = dp.valid.shape[0]
+        if isinstance(assigned, np.ndarray) and assigned.shape[0] < n:
+            pad = np.full((n,), -1, np.int32)
+            pad[: assigned.shape[0]] = assigned
+            assigned = pad
+        return quality_reduce(jnp.asarray(assigned, jnp.int32),
+                              usage.requested, dp, dn)
+
+    def _quality_read(self, q_dev, res=None, batch=(), assigned=None,
+                      nt=None) -> Dict[str, float]:
+        """Second half: read the vector back and decode it. Given the
+        cycle's ``res``, the pack's host-side scores over the final
+        assignment join it and the cycle publishes it."""
+        from kubernetes_tpu.scenarios.quality import decode_quality
+
+        with self.obs.span("pipeline:readback@quality"):
+            quality = decode_quality(
+                self.obs.jax.readback("scenario-quality", q_dev))
+        if res is not None:
+            quality.update(
+                self.scenario_pack.quality_host(batch, assigned, nt))
+            res.scenario_quality = quality
+            self._publish_scenario_quality(quality)
+        return quality
+
     def _publish_scenario_quality(self, quality) -> None:
         """Fan one cycle's quality dict out to the flight record and
         the gauge family — scores that stopped being reported (a
@@ -4317,7 +4245,9 @@ class Scheduler:
             dn = None
         node_order = self.cache.node_order()
         pt = pk.pack_pods(pods_list)
-        skip_prio, no_ports, no_pod_aff, no_spread = solver_gates(nt, pt)
+        key = self._solve_key(
+            self.solver if self.solver != "exact" else "batch",
+            solver_gates(nt, pt))
         if dn is None:
             if self._mesh_live:
                 from kubernetes_tpu.parallel.mesh import place_node_table
@@ -4340,18 +4270,9 @@ class Scheduler:
         if self.scenario_pack is not None:
             extra_score = self.scenario_pack.cost(
                 pods_list, nt, node_order, dp, dn)
-        solver = self.solver if self.solver != "exact" else "batch"
-        self.obs.jax.record_call(
-            "solve", dp, dn, ds, dt, dv,
-            static=(solver, tuple(skip_prio), no_ports, no_pod_aff,
-                    no_spread, self.pred_mask, self.per_node_cap,
-                    self.max_rounds, True, extra_score is None,
-                    self._mesh_live),
-        )
         ladder = self._solve_ladder(
-            solver, pods_list, dp, dn, ds, dt, dv, sv, None, None,
-            extra_score, skip_prio, no_ports, no_pod_aff, no_spread, res,
-        )
+            key, pods_list, dp, dn, ds, dt, dv, sv, None, None,
+            extra_score, res, record=True)
         cycle = self.queue.scheduling_cycle
         if ladder is None:
             for p in pods_list:
@@ -4359,19 +4280,9 @@ class Scheduler:
                     self._fail(p, cycle, res, ("SolverUnavailable",))
             return placed, None
         assigned, usage2, _rounds, _tier = ladder
-        q2 = None
-        if self.scenario.quality:
-            from kubernetes_tpu.ops.scenario_cost import quality_reduce
-            from kubernetes_tpu.scenarios.quality import decode_quality
-
-            pad_a = np.full((dp.valid.shape[0],), -1, np.int32)
-            pad_a[: len(pods_list)] = assigned[: len(pods_list)]
-            with self.obs.span("pipeline:readback@quality"):
-                q2 = decode_quality(self.obs.jax.readback(
-                    "scenario-quality",
-                    quality_reduce(jnp.asarray(pad_a), usage2.requested,
-                                   dp, dn)))
         assigned = assigned[: len(pods_list)]
+        q_dev = self._quality_dispatch(assigned, usage2, dp, dn)
+        q2 = self._quality_read(q_dev) if q_dev is not None else None
         for i, p in enumerate(pods_list):
             t = int(assigned[i])
             if t < 0:
@@ -4491,17 +4402,9 @@ class Scheduler:
         ds = self._place(selectors_to_device(pk.pack_selector_tables()))
         dt = self._place(topology_to_device(pk.pack_topology_tables())
                          if _has_topo(pk.u) else None)
-        pt_all = pk.pack_pods(sample)
-        skip_prio, no_ports, no_pod_aff, no_spread = solver_gates(nt, pt_all)
-        solver = self.solver if self.solver != "exact" else "batch"
-        statics = (solver, tuple(skip_prio), no_ports, no_pod_aff,
-                   no_spread, self.pred_mask, self.per_node_cap,
-                   self.max_rounds, True,
-                   # a scenario pack fills extra_score every cycle; the
-                   # warmed signature must carry the same trace-time
-                   # fact or the first real cycle recompiles
-                   self.scenario_pack is None,
-                   self._mesh_live)
+        key = self._solve_key(
+            self.solver if self.solver != "exact" else "batch",
+            solver_gates(nt, pk.pack_pods(sample)))
         buckets = tuple(wu.pod_buckets)
         if not buckets:
             # geometric x2 steps up to bucket_size(max_batch) — the
@@ -4526,9 +4429,8 @@ class Scheduler:
                     # device-loss chaos seam for the compile below
                     self.fault_injector.device_hook("warmup:compile")
                 compiled += self._warm_bucket(
-                    P, pk, sample, nt, dn, ds, dt, solver, statics,
-                    (skip_prio, no_ports, no_pod_aff, no_spread),
-                    has_vol_sample, wu, anchor=(compiled == 0))
+                    P, pk, sample, nt, dn, ds, dt, key, has_vol_sample, wu,
+                    anchor=(compiled == 0))
             except Exception as e:
                 # a lost/OOMed device during an AOT compile (injected
                 # OR a real XLA runtime error — warmup runs inside the
@@ -4575,7 +4477,7 @@ class Scheduler:
             # and the candidate bucket C is one static shape
             try:
                 compiled += self._warm_incremental(buckets, pk, sample,
-                                                   nt, dn, ds, skip_prio)
+                                                   nt, dn, ds, key)
             except Exception as e:
                 klog.warning("incremental warmup aborted: %s", e)
         if wu.host_fallback and self.mesh is not None and self._mesh_live:
@@ -4595,16 +4497,14 @@ class Scheduler:
                 ds_h = selectors_to_device(pk.pack_selector_tables())
                 dt_h = (topology_to_device(pk.pack_topology_tables())
                         if _has_topo(pk.u) else None)
-                statics_h = statics[:-1] + (False,)
+                key_h = key._replace(mesh_live=False)
                 for P in buckets:
                     try:
                         if self.fault_injector is not None:
                             self.fault_injector.device_hook(
                                 "warmup:compile")
                         compiled += self._warm_bucket(
-                            P, pk, sample, nt, dn_h, ds_h, dt_h, solver,
-                            statics_h,
-                            (skip_prio, no_ports, no_pod_aff, no_spread),
+                            P, pk, sample, nt, dn_h, ds_h, dt_h, key_h,
                             has_vol_sample, wu)
                     except Exception as e:
                         self.metrics.recovery_device_resets.inc()
@@ -4629,8 +4529,7 @@ class Scheduler:
                     # pay a hot-path compile either
                     try:
                         compiled += self._warm_incremental(
-                            buckets, pk, sample, nt, dn_h, ds_h,
-                            skip_prio)
+                            buckets, pk, sample, nt, dn_h, ds_h, key_h)
                     except Exception as e:
                         klog.warning("incremental host-fallback warmup "
                                      "aborted: %s", e)
@@ -4650,8 +4549,8 @@ class Scheduler:
         n = len(pk.owner_cols_of(sample[:P]))
         return owner_width(P if 0 < n == len(sample) < P else n)
 
-    def _warm_bucket(self, P, pk, sample, nt, dn, ds, dt, solver, statics,
-                     gates, has_vol_sample, wu, anchor: bool = False) -> int:
+    def _warm_bucket(self, P, pk, sample, nt, dn, ds, dt, key,
+                     has_vol_sample, wu, anchor: bool = False) -> int:
         """Compile one bucketed solve shape (the body of the warmup
         sweep); returns 1. Split out so the sweep's device-loss
         handling wraps the WHOLE per-bucket compile — injected chaos
@@ -4664,13 +4563,6 @@ class Scheduler:
         scales from."""
         import jax
 
-        from kubernetes_tpu.ops.assign import (
-            batch_assign,
-            device_validate,
-            greedy_assign,
-        )
-
-        skip_prio, no_ports, no_pod_aff, no_spread = gates
         pt = pk.pack_pods(sample[:P], owner_pad=self._warm_owner_pad(
             P, pk, sample))
         dn = self._with_owner_columns(dn, pt)
@@ -4695,61 +4587,31 @@ class Scheduler:
             # recompile on the hot path
             extra_score = self.scenario_pack.cost(
                 sample[:P], nt, self.cache.node_order(), dp, dn)
-        # the extra-score static must mirror what the WARM cost call
-        # actually produced (a pack whose cost() returns None would
-        # otherwise pre-register a signature no real cycle presents)
-        statics = statics[:9] + (extra_score is None,) + statics[10:]
-        self.obs.jax.record_call("solve", dp, dn, ds, dt, dv,
-                                 static=statics, warmup=True)
-        if solver == "greedy":
-            a, wu_usage = greedy_assign(
-                dp, dn, ds, self.weights, topo=dt, vol=dv,
-                static_vol=sv,
-                enabled_mask=self.pred_mask, extra_score=extra_score,
-                skip_priorities=skip_prio,
-                no_ports=no_ports, no_pod_affinity=no_pod_aff,
-                no_spread=no_spread,
-            )
-        else:
-            solve_kwargs = dict(
-                max_rounds=self.max_rounds,
-                per_node_cap=self.per_node_cap, topo=dt, vol=dv,
-                static_vol=sv, enabled_mask=self.pred_mask,
-                extra_score=extra_score,
-                use_sinkhorn=(solver == "sinkhorn"),
-                skip_priorities=skip_prio, no_ports=no_ports,
-                no_pod_affinity=no_pod_aff, no_spread=no_spread,
-                stats_out=self.obs.config.sinkhorn_telemetry,
-            )
-            out = batch_assign(dp, dn, ds, self.weights, **solve_kwargs)
-            a, wu_usage = out[0], out[1]
+        # the signature's extra-score flag follows what the WARM cost
+        # call actually produced (a pack whose cost() returns None must
+        # not pre-register a signature no real cycle presents)
+        ops = dict(topo=dt, vol=dv, static_vol=sv, extra_score=extra_score)
+        a, wu_usage, _ = self._solve(key, key.solver, dp, dn, ds,
+                                     warmup=True, **ops)
+        if key.solver != "greedy":
             if anchor and self.obs.ledger.enabled:
-                self._anchor_cost_model(dp, dn, ds, a, solve_kwargs)
+                self._anchor_cost_model(key, dp, dn, ds, a, ops)
             if self.obs.memledger.preflight_on:
                 # EVERY bucket feeds the capacity preflight's per-shape
                 # peak table (the anchor above only samples the first) —
                 # the preflight can only split down to shapes it has a
                 # measured budget for
-                self._capture_bucket_memory(dp, dn, ds, solve_kwargs)
-        if (self.robustness.validate_results
-                and not self.robustness.host_validate):
-            # the fused validator rides every production cycle's
-            # readback — compile its program per bucket here too, or
-            # the first real cycle pays it on the hot path
-            dv_out = device_validate(a, wu_usage, dp, dn,
-                                     self.pred_mask)
-            if dv_out is not None:
-                jax.block_until_ready(dv_out[0])
-        if self.scenario_pack is not None and self.scenario.quality:
+                self._capture_bucket_memory(key, key.solver, dp, dn, ds,
+                                            ops)
+        self._warm_validate(a, wu_usage, dp, dn)
+        q_dev = self._quality_dispatch(np.full((P,), -1, np.int32),
+                                       wu_usage, dp, dn)
+        if q_dev is not None:
             # the per-cycle quality reduction rides every scenario
             # cycle's readback — compile its program per bucket here
             # too, with the host-built assignment vector real cycles
             # upload (same placement, same signature)
-            from kubernetes_tpu.ops.scenario_cost import quality_reduce
-
-            pad_a = jnp.asarray(np.full((P,), -1, np.int32))
-            jax.block_until_ready(
-                quality_reduce(pad_a, wu_usage.requested, dp, dn))
+            jax.block_until_ready(q_dev)
         jax.block_until_ready(a)
         fr_mask = None
         if wu.include_filter:
@@ -4770,36 +4632,27 @@ class Scheduler:
             if fr_mask is None:
                 fr_mask = _filter_pass(dp, dn, ds, dt, dv, sv,
                                        self.pred_mask).mask
-            self.obs.jax.record_call(
-                "solve", dp, dn, ds, dt, dv,
-                static=statics[:8] + (False,) + statics[9:],
-                warmup=True)
-            if solver == "greedy":
-                a_m, _ = greedy_assign(
-                    dp, dn, ds, self.weights, topo=dt,
-                    extra_mask=fr_mask, vol=dv, static_vol=sv,
-                    enabled_mask=self.pred_mask, extra_score=extra_score,
-                    skip_priorities=skip_prio, no_ports=no_ports,
-                    no_pod_affinity=no_pod_aff, no_spread=no_spread,
-                )
-            else:
-                out_m = batch_assign(
-                    dp, dn, ds, self.weights,
-                    max_rounds=self.max_rounds,
-                    per_node_cap=self.per_node_cap, topo=dt,
-                    extra_mask=fr_mask, vol=dv, static_vol=sv,
-                    enabled_mask=self.pred_mask, extra_score=extra_score,
-                    use_sinkhorn=(solver == "sinkhorn"),
-                    skip_priorities=skip_prio, no_ports=no_ports,
-                    no_pod_affinity=no_pod_aff, no_spread=no_spread,
-                    stats_out=self.obs.config.sinkhorn_telemetry,
-                )
-                a_m = out_m[0]
-            jax.block_until_ready(a_m)
+            jax.block_until_ready(self._solve(
+                key, key.solver, dp, dn, ds, extra_mask=fr_mask,
+                warmup=True, **ops)[0])
         self.metrics.warmup_compiles.inc()
         return 1
 
-    def _anchor_cost_model(self, dp, dn, ds, warm_a, solve_kwargs) -> None:
+    def _warm_validate(self, a, usage, dp, dn) -> None:
+        """Compile the fused validator at this shape: it rides every
+        served cycle's readback, so left to first sight the first real
+        cycle would pay its compile on the hot path."""
+        import jax
+
+        from kubernetes_tpu.ops.assign import device_validate
+
+        rc = self.robustness
+        if rc.validate_results and not rc.host_validate:
+            dv_out = device_validate(a, usage, dp, dn, self.pred_mask)
+            if dv_out is not None:
+                jax.block_until_ready(dv_out[0])
+
+    def _anchor_cost_model(self, key, dp, dn, ds, warm_a, ops) -> None:
         """The perf ledger's model-side warmup capture (obs/ledger.py):
         (a) the compiled solve signature's XLA ``cost_analysis`` flops /
         bytes-accessed (best-effort AOT — some backends decline), and
@@ -4816,24 +4669,22 @@ class Scheduler:
 
         import jax
 
-        from kubernetes_tpu.ops.assign import (
-            batch_assign,
-            solve_cost_analysis,
-        )
+        from kubernetes_tpu.ops.assign import solve_cost_analysis
 
         ledger = self.obs.ledger
         P_pad = int(dp.valid.shape[0])
         N_pad = int(dn.valid.shape[0])
         mesh = int(self.mesh.devices.size) if self._mesh_live else 0
         try:
-            ca = solve_cost_analysis(dp, dn, ds, self.weights,
-                                     **solve_kwargs)
+            ca = solve_cost_analysis(dp, dn, ds, key.weights,
+                                     **key.batch_kwargs(key.solver), **ops)
             if ca is not None:
                 ledger.model.record_signature(
                     P_pad, N_pad, ca["flops"], ca["bytes_accessed"])
             jax.block_until_ready(warm_a)  # the compile, not the replay
             t0 = _time.perf_counter()
-            out = batch_assign(dp, dn, ds, self.weights, **solve_kwargs)
+            out = self._solve(key, key.solver, dp, dn, ds, record=False,
+                              warmup=True, **ops)
             jax.block_until_ready(out[0])
             elapsed = _time.perf_counter() - t0
             # batch_assign's 3rd output is the executed round count —
@@ -4847,7 +4698,7 @@ class Scheduler:
         except Exception as e:
             klog.V(2).info("ledger cost-model capture skipped: %s", e)
 
-    def _capture_bucket_memory(self, dp, dn, ds, solve_kwargs) -> None:
+    def _capture_bucket_memory(self, key, tier, dp, dn, ds, ops) -> None:
         """The memory ledger's per-bucket peak capture (obs/memledger):
         AOT-lower the solve at THIS warmed (P, N) shape and read the
         compiled program's ``memory_analysis`` — argument/output/temp
@@ -4860,8 +4711,8 @@ class Scheduler:
 
         ml = self.obs.memledger
         try:
-            ma = solve_memory_analysis(dp, dn, ds, self.weights,
-                                       **solve_kwargs)
+            ma = solve_memory_analysis(dp, dn, ds, key.weights,
+                                       **key.batch_kwargs(tier), **ops)
             if ma is not None:
                 ml.record_bucket_memory(
                     int(dp.valid.shape[0]), int(dn.valid.shape[0]),
@@ -4895,7 +4746,7 @@ class Scheduler:
         return compiled
 
     def _warm_incremental(self, buckets, pk, sample, nt, dn, ds,
-                          skip_prio) -> int:
+                          key) -> int:
         """Pre-compile the restricted-solve programs for every pod
         bucket that can take the incremental route: the (mesh-sharded)
         candidate pick — top-k over the cached plane, per-shard local
@@ -4923,7 +4774,6 @@ class Scheduler:
             gather_node_rows,
             map_restricted_assignment,
         )
-        from kubernetes_tpu.ops.assign import batch_assign, device_validate
         from kubernetes_tpu.ops.fused_score import (
             node_summary,
             partition_columns,
@@ -4953,9 +4803,7 @@ class Scheduler:
                 node_summary(dn, **flags), node_summary(sub, **flags),
                 np.full((dpb,), n_pad, np.int32))
             jax.block_until_ready(patched.rank)
-        use_sk = self.solver == "sinkhorn"
-        warm = bool(inc.warm_potentials and use_sk)
-        want_stats = bool(self.obs.config.sinkhorn_telemetry and use_sk)
+        warm = key.warm_tol is not None
         pack = (self.scenario_pack
                 if (self.scenario_pack is not None
                     and self.scenario_pack.restricted_ok) else None)
@@ -5036,15 +4884,6 @@ class Scheduler:
                     # restricted cycles feed extra_score with
                     extra = pack.cost(sample[:P], nt, node_order, dp,
                                       sub_w)
-                solve_kwargs = dict(
-                    max_rounds=self.max_rounds,
-                    per_node_cap=self.per_node_cap,
-                    enabled_mask=self.pred_mask, use_sinkhorn=use_sk,
-                    skip_priorities=skip_prio, no_ports=True,
-                    no_pod_affinity=True, no_spread=True,
-                    stats_out=want_stats,
-                    sk_tol=(inc.warm_tol if warm else None),
-                    potentials_out=warm)
                 variants = [dict(sk_init=None, extra_score=None)]
                 if warm and not over_limit:
                     # the warm-started program is a DIFFERENT signature
@@ -5062,48 +4901,27 @@ class Scheduler:
                         variants.append(dict(sk_init=zp,
                                              extra_score=extra))
                 for var in variants:
-                    self.obs.jax.record_call(
-                        "solve", dp, sub_w, ds,
-                        static=("restricted", self.solver,
-                                tuple(skip_prio), self.pred_mask,
-                                self.per_node_cap, self.max_rounds,
-                                var["sk_init"] is None,
-                                var["extra_score"] is None,
-                                self._mesh_live),
-                        warmup=True)
-                    out = batch_assign(dp, sub_w, ds, self.weights,
-                                       **solve_kwargs, **var)
+                    out = self._solve(key, "restricted", dp, sub_w, ds,
+                                      warmup=True, **var)
                     a, wu_usage = out[0], out[1]
-                    if (self.robustness.validate_results
-                            and not self.robustness.host_validate):
-                        dv_out = device_validate(a, wu_usage, dp,
-                                                 sub_w, self.pred_mask)
-                        if dv_out is not None:
-                            jax.block_until_ready(dv_out[0])
+                    self._warm_validate(a, wu_usage, dp, sub_w)
                     jax.block_until_ready(
                         map_restricted_assignment(a, cand))
-                if (pack is not None and self.scenario.quality
-                        and not over_limit):
+                if pack is not None and not over_limit:
                     # the frame-local quality reduction rides every
                     # scenario restricted cycle's readback — compile
                     # its (P, C) program here too
-                    from kubernetes_tpu.ops.scenario_cost import (
-                        quality_reduce,
-                    )
-
-                    jax.block_until_ready(quality_reduce(
-                        a.astype(jnp.int32), wu_usage.requested, dp,
-                        sub_dn))
+                    q_dev = self._quality_dispatch(a, wu_usage, dp, sub_dn)
+                    if q_dev is not None:
+                        jax.block_until_ready(q_dev)
                 if self.obs.memledger.preflight_on:
                     # the preflight's peak table learns the restricted
                     # shapes too — (P, C) rows are what an over-budget
                     # dense 50k solve splits DOWN to instead of OOMing
                     # (warm-start extras are solve-only knobs the AOT
                     # analysis signature does not take)
-                    self._capture_bucket_memory(
-                        dp, sub_dn, ds,
-                        {k: v for k, v in solve_kwargs.items()
-                         if k not in ("sk_tol", "potentials_out")})
+                    self._capture_bucket_memory(key, "restricted", dp,
+                                                sub_dn, ds, {})
                 compiled += 1
                 self.metrics.warmup_compiles.inc()
             self._warmed_cbuckets.add(C)

@@ -359,6 +359,89 @@ def test_partitioned_under_placed_declines_to_dense():
         scope="under-placed") >= 1
 
 
+def _assert_placements_valid(nodes, pods, assignments):
+    """Every placement of the run, checked jointly against the empty
+    cluster by the host checker (ops/assign.validate_solution)."""
+    from kubernetes_tpu.ops.arrays import nodes_to_device, pods_to_device
+    from kubernetes_tpu.ops.assign import usage_from_nodes, validate_solution
+    from kubernetes_tpu.snapshot import SnapshotPacker
+
+    pk = SnapshotPacker()
+    for p in pods:
+        pk.intern_pod(p)
+    dn = nodes_to_device(pk.pack_nodes(nodes))
+    dp = pods_to_device(pk.pack_pods(pods))
+    row = {nd.name: i for i, nd in enumerate(nodes)}
+    a = np.full((dp.valid.shape[0],), -1, np.int32)
+    a[: len(pods)] = [row[assignments[p.key()]] for p in pods]
+    assert validate_solution(a, usage_from_nodes(dn), dp, dn) == (True, "")
+
+
+@pytest.mark.parametrize("host_validate", [False, True],
+                         ids=["fused", "host"])
+@pytest.mark.parametrize("route", ["restricted", "partitioned"])
+def test_lying_frame_solver_declines_to_dense(route, host_validate):
+    """A frame route's solver that lies — every pod of the batch on the
+    frame's first column, four 25-core pods on one 64-core node — is
+    caught by the shared validated readback under fused AND forced-host
+    validation alike: the route declines, binds nothing, and the dense
+    ladder places the batch in the same cycle."""
+    from kubernetes_tpu.config import RobustnessConfig
+    from kubernetes_tpu.faults import FaultInjector
+
+    fi = FaultInjector(seed=3)
+    s = _build(incremental=IncrementalConfig(
+        enabled=True, primary=(route == "partitioned"),
+        candidate_bucket=32),
+        robustness=RobustnessConfig(host_validate=host_validate),
+        fault_injector=fi)
+    nodes = [s.cache.node(f"n{i}") for i in range(96)]
+    pods, assignments = [], {}
+    if route == "restricted":
+        # an honest cold cycle first: the restricted route needs a warm
+        # resident snapshot
+        pods = [make_pod(f"warm-{i}", cpu_milli=50) for i in range(4)]
+        for p in pods:
+            s.on_pod_add(p)
+        r0 = s.schedule_cycle()
+        assert r0.scheduled == 4
+        assignments.update(r0.assignments)
+    fi.arm(f"solve:{route}", "infeasible")
+    heavy = [make_pod(f"heavy-{i}", cpu_milli=25_000) for i in range(4)]
+    for p in heavy:
+        s.on_pod_add(p)
+    r = s.schedule_cycle()
+    assert fi.fired_total(f"solve:{route}") >= 1
+    assert s.metrics.incremental_cycles.value(scope="declined") >= 1
+    assert r.solve_scope == "full"
+    assert r.scheduled == 4
+    pods += heavy
+    assignments.update(r.assignments)
+    _assert_placements_valid(nodes, pods, assignments)
+
+
+def test_warmed_partitioned_then_restricted_never_retrace():
+    """Warmup and the served frame routes build their solve signature
+    from one key: after warmup, a cold PARTITIONED cycle (a node delete
+    forces the full-snapshot rebuild) and then restricted cycles across
+    the warmed pod buckets add zero retraces at any site."""
+    s = _build(warm=True)
+    _churn(s, 3, "a")
+    assert s.schedule_cycle().solve_scope == "restricted"
+    s.on_node_delete("n95")
+    _churn(s, 3, "cold")
+    r = s.schedule_cycle()
+    assert r.solve_scope == "partitioned"
+    assert r.scheduled == 3
+    for n, tag in ((7, "b"), (2, "c"), (4, "d")):
+        _churn(s, n, tag)
+        r = s.schedule_cycle()
+        assert r.solve_scope == "restricted"
+        assert r.scheduled == n
+    assert s.obs.jax.calls.get("solve", 0) >= 4
+    assert s.obs.jax.retrace_total() == 0
+
+
 def test_gangs_and_packs_keep_dense_cold_semantics():
     """Cold-route polarity: a gang batch keeps the dense oracle's
     monolithic cold solve (rollback + failure analytics want the full
